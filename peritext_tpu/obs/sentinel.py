@@ -8,16 +8,15 @@ from typing import Dict, Optional
 
 from .metrics import Counters, GLOBAL_COUNTERS
 
-#: jax's log_compiles emission: "Compiling <site> with global shapes and
-#: types ..." (pxla) / "Compiling <site> for ..." (older dispatch paths).
-#: Matched with ``search``, anywhere in the record — handlers downstream of
+#: jax's log_compiles emission: "Compiling jit(<site>) with global shapes
+#: and types ..." — the site is the name inside ``jit(...)``.  Matched with ``search``, anywhere in the record — handlers downstream of
 #: other logging layers can receive the message PREFIXED (formatter noise,
 #: "%(asctime)s ... Compiling f ...") or MULTI-LINE (a "Finished tracing +
 #: transforming <site> ..." line batched ahead of the Compiling line), and
 #: an anchored match silently counted zero compiles for those.  The word
 #: boundary keeps "XLA compilation"/"Recompiling"-style prose from
 #: false-matching.
-_COMPILE_MSG_RE = re.compile(r"\bCompiling (\S+)")
+_COMPILE_MSG_RE = re.compile(r"\bCompiling jit\(([^)\s]+)\)")
 
 
 class RecompileSentinel(logging.Handler):

@@ -36,10 +36,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernel builds against both sides of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 LANES = 128
 
 
@@ -173,7 +169,7 @@ def insert_batch_pallas(elem_id, char, num_slots, overflow,
         stream_index = lambda i, j: (j, i)  # noqa: E731
         kernel = _insert_kernel_chunked
         params = dict(
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT,
             )
@@ -184,7 +180,7 @@ def insert_batch_pallas(elem_id, char, num_slots, overflow,
         stream_index = index
         kernel = _insert_kernel
         params = dict(
-            compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
         )
 
     state_col = lambda width: pl.BlockSpec(  # noqa: E731

@@ -6,7 +6,7 @@ its ragged twin over the page pool: the grid is one cell per BATCH DOC, the
 doc's page table rides in scalar-prefetch memory (so the gather targets are
 known before the cell body runs), and each cell
 
-1. DMA-gathers the doc's TRUE pages from the pool (ANY/HBM refs) into a
+1. DMA-gathers the doc's TRUE pages from the pool (HBM refs) into a
    ``(max_doc_pages, P)`` VMEM scratch window,
 2. runs the doc's TRUE insert count through the RGA insert loop on that
    window — the same masked-reduction formulation as pallas_insert
@@ -40,9 +40,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# module imports across the versions the container may carry.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from .pallas_insert import LANES
 
 #: reuse the padded kernel's "no position" sentinel discipline; far above
 #: any slot position, far below int32 max so +1 arithmetic stays safe
@@ -64,23 +62,23 @@ def ragged_vmem_ok(gmax: int, page_size: int, k_ins: int) -> bool:
 
 def _ragged_insert_kernel(
     # scalar prefetch
-    page_table_ref,  # (B, Gmax) pool page per (doc, doc-page) — 0 = null
+    page_table_ref,  # (B * Gmax,) pool page per (doc, doc-page) — 0 = null
     page_count_ref,  # (B,) true page count per doc
     ins_count_ref,   # (B,) true insert count per doc
+    n_ref,           # (B,) slot count before the round
+    ov_ref,          # (B,) overflow flag before the round (int32)
+    cap_ref,         # (B,) allocated slot coverage
     # inputs
-    pool_elem_hbm,   # (N, P) ANY — aliased with out
-    pool_char_hbm,   # (N, P) ANY — aliased with out
-    n_ref,           # (1, 1) block of (B, 1)
-    ov_ref,          # (1, 1) block of (B, 1) int32
-    cap_ref,         # (1, 1) block of (B, 1)
-    ins_ref_ref,     # (1, KI) block
-    ins_op_ref,      # (1, KI) block
-    ins_char_ref,    # (1, KI) block
+    pool_elem_hbm,   # (N, P) HBM — aliased with out
+    pool_char_hbm,   # (N, P) HBM — aliased with out
+    ins_ref_ref,     # (1, KI) SMEM block of (B, 1, KI)
+    ins_op_ref,      # (1, KI) SMEM block of (B, 1, KI)
+    ins_char_ref,    # (1, KI) SMEM block of (B, 1, KI)
     # outputs
-    out_elem_hbm,    # (N, P) ANY — IS pool_elem_hbm (aliased)
-    out_char_hbm,    # (N, P) ANY — IS pool_char_hbm (aliased)
-    n_out_ref,       # (1, 1)
-    ov_out_ref,      # (1, 1)
+    out_elem_hbm,    # (N, P) HBM — IS pool_elem_hbm (aliased)
+    out_char_hbm,    # (N, P) HBM — IS pool_char_hbm (aliased)
+    n_out_ref,       # (1, 1) VMEM block of (B, 1, 1)
+    ov_out_ref,      # (1, 1) VMEM block of (B, 1, 1)
     # scratch
     elem_scr,        # VMEM (Gmax, P)
     char_scr,        # VMEM (Gmax, P)
@@ -98,7 +96,7 @@ def _ragged_insert_kernel(
     char_scr[...] = jnp.zeros((gmax, p), jnp.int32)
 
     def _gather(j, _):
-        pg = page_table_ref[i, j]
+        pg = page_table_ref[i * gmax + j]
         cp = pltpu.make_async_copy(
             pool_elem_hbm.at[pl.ds(pg, 1), :], elem_scr.at[pl.ds(j, 1), :],
             dma_sem,
@@ -115,9 +113,9 @@ def _ragged_insert_kernel(
 
     lax.fori_loop(0, g, _gather, 0)
 
-    n0 = n_ref[0, 0]
-    ov0 = ov_ref[0, 0]
-    cap = cap_ref[0, 0]
+    n0 = n_ref[i]
+    ov0 = ov_ref[i]
+    cap = cap_ref[i]
     lane = lax.broadcasted_iota(jnp.int32, (gmax, p), 1)
     grow = lax.broadcasted_iota(jnp.int32, (gmax, p), 0)
     pos = grow * jnp.int32(p) + lane
@@ -162,11 +160,11 @@ def _ragged_insert_kernel(
         )
 
     n1, ov1 = lax.fori_loop(0, ins_count_ref[i], _body, (n0, ov0))
-    n_out_ref[0, 0] = n1
-    ov_out_ref[0, 0] = ov1
+    n_out_ref[...] = jnp.full((1, 1), n1, jnp.int32)
+    ov_out_ref[...] = jnp.full((1, 1), ov1, jnp.int32)
 
     def _scatter(j, _):
-        pg = page_table_ref[i, j]
+        pg = page_table_ref[i * gmax + j]
         cp = pltpu.make_async_copy(
             elem_scr.at[pl.ds(j, 1), :], out_elem_hbm.at[pl.ds(pg, 1), :],
             dma_sem,
@@ -196,15 +194,29 @@ def ragged_insert_pallas(
     b, ki = ins_op.shape
     n, p = pool_elem.shape
     gmax = page_table.shape[1]
-    col = lambda w: pl.BlockSpec(  # noqa: E731
-        (1, w), lambda i, *_: (i, 0), memory_space=pltpu.VMEM
+    if p % LANES and not interpret:
+        raise ValueError(
+            f"page size {p} is not a multiple of {LANES}: the chip cannot "
+            f"DMA a page out of the lane-tiled pool"
+        )
+    # Mosaic requires a block's last two dims to be (8, 128)-aligned or
+    # equal to the array's, so a per-doc block of a (B, w) operand is
+    # illegal: the streams ride as (B, 1, KI) in SMEM (read one scalar per
+    # insert step), the per-doc outputs as (B, 1, 1), and the per-doc
+    # scalars ride scalar prefetch with the page table — flattened, since
+    # SMEM pads a 2-D array's last dim to 128 words.
+    stream = pl.BlockSpec(
+        (None, 1, ki), lambda i, *_: (i, 0, 0), memory_space=pltpu.SMEM
     )
-    pool = pl.BlockSpec(memory_space=pltpu.ANY)
+    scalar_out = pl.BlockSpec(
+        (None, 1, 1), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
+    )
+    pool = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=6,
         grid=(b,),
-        in_specs=[pool, pool, col(1), col(1), col(1), col(ki), col(ki), col(ki)],
-        out_specs=[pool, pool, col(1), col(1)],
+        in_specs=[pool, pool, stream, stream, stream],
+        out_specs=[pool, pool, scalar_out, scalar_out],
         scratch_shapes=[
             pltpu.VMEM((gmax, p), jnp.int32),
             pltpu.VMEM((gmax, p), jnp.int32),
@@ -217,23 +229,21 @@ def ragged_insert_pallas(
         out_shape=[
             jax.ShapeDtypeStruct((n, p), pool_elem.dtype),
             jax.ShapeDtypeStruct((n, p), pool_char.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         ],
         # flattened-leaf indices, scalar-prefetch operands included
-        # (page_table=0, page_count=1, ins_counts=2, pool_elem=3, pool_char=4)
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=_CompilerParams(
+        # (six prefetch planes, then pool_elem=6, pool_char=7)
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
     )(
-        page_table, page_count, ins_counts,
+        page_table.reshape(-1), page_count, ins_counts,
+        n0.astype(jnp.int32), ov0.astype(jnp.int32), cap.astype(jnp.int32),
         pool_elem, pool_char,
-        n0[:, None].astype(jnp.int32),
-        ov0[:, None].astype(jnp.int32),
-        cap[:, None].astype(jnp.int32),
-        ins_ref, ins_op, ins_char,
+        ins_ref[:, None, :], ins_op[:, None, :], ins_char[:, None, :],
     )
-    return out_elem, out_char, n1[:, 0], ov1[:, 0] != 0
+    return out_elem, out_char, n1[:, 0, 0], ov1[:, 0, 0] != 0

@@ -38,7 +38,7 @@ _MAGIC = b"PTXF"
 #: wire version this codec EMITS; both 1 and 2 are decoded.  v2 adds per-op
 #: delta flags (below) that elide the redundant ids dominating v1's
 #: ~12 bytes/op, roughly halving bytes/op and thereby doubling the op rate
-#: any fixed-bandwidth DCN/tunnel link can carry (VERDICT r2 weak #4).
+#: any fixed-bandwidth DCN link can carry (VERDICT r2 weak #4).
 _VERSION = 2
 #: v3/v4 are SESSION-scoped transport versions (VERDICT r3 task 3): the
 #: string table persists across a peer link's frames (each frame advertises

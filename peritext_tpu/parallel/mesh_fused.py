@@ -116,7 +116,7 @@ def page_mover_fn(mesh, m_pages: int, m_zero: int) -> Callable:
 
     Returns the updated ``(pool_elem, pool_char)``.  Cache through
     :func:`mesh_fn` with key ``("page_mover", m_pages, m_zero)``."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from jax.sharding import PartitionSpec as P
 

@@ -42,13 +42,11 @@ from ..ops.packed import PackedDocs, empty_docs
 from ..utils.shapes import next_pow2
 from .alloc import PageAllocator, PoolExhausted
 
-#: Default op-page width.  Chosen from the PR-5 devprof cost snapshots (see
-#: DESIGN.md "Paged storage"): the insert phase is HBM-bound on two (B, W)
-#: planes, and modeled bytes-accessed flattens once rows are >= ~256 B
-#: (64 int32 lanes) while internal fragmentation grows linearly with P —
-#: 64 slots/page keeps worst-case per-doc fragmentation under one tweet
-#: and the page tables tiny.
-DEFAULT_PAGE_SIZE = 64
+#: Default op-page width: one TPU lane row (128 int32).  The ragged Pallas
+#: kernel DMAs whole pages out of the HBM pool, whose rows are tiled 128
+#: lanes wide, so a page narrower than a lane row cannot be sliced out on
+#: the chip (ops/ragged_pallas.py); any width divides the work on the CPU.
+DEFAULT_PAGE_SIZE = 128
 
 
 def _pow2(n: int) -> int:

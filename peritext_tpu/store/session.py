@@ -471,7 +471,7 @@ class PagedStreamingMerge(StreamingMerge):
         donate = resolve_state_donation(self._store.pool_elem)
 
         def build():
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(pool_elem, pool_char, aux, group_inputs):
@@ -482,10 +482,13 @@ class PagedStreamingMerge(StreamingMerge):
                     insert_impl=impl,
                 )
 
+            # check_vma=False: pallas_call results carry no varying-axes
+            # type; every operand and result is doc-sharded anyway
             wrapped = shard_map(
                 body, mesh=mesh,
                 in_specs=(P(_mesh.DOC_AXIS),) * 4,
                 out_specs=(P(_mesh.DOC_AXIS),) * 3,
+                check_vma=False,
             )
             return jax.jit(
                 wrapped, donate_argnums=(0, 1, 2) if donate else ())
@@ -940,7 +943,7 @@ class RaggedStreamingMerge(PagedStreamingMerge):
         donate = resolve_state_donation(self._store.pool_elem)
 
         def build():
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(pool_elem, pool_char, aux, planes, earrays,
@@ -954,14 +957,13 @@ class RaggedStreamingMerge(PagedStreamingMerge):
                     ins_counts, del_counts, ragged_impl=impl,
                 )
 
-            # check_rep=False: the ragged pool walk is lax.fori_loop-based
-            # and shard_map has no replication rule for while — every
-            # operand and result is explicitly doc-axis-sharded anyway
+            # check_vma=False: pallas_call results carry no varying-axes
+            # type; every operand and result is doc-sharded anyway
             wrapped = shard_map(
                 body, mesh=mesh,
                 in_specs=(P(_mesh.DOC_AXIS),) * 7,
                 out_specs=(P(_mesh.DOC_AXIS),) * 3,
-                check_rep=False,
+                check_vma=False,
             )
             return jax.jit(
                 wrapped, donate_argnums=(0, 1, 2) if donate else ())

@@ -1,4 +1,4 @@
-"""Platform pinning for virtual-mesh (CPU) runs.
+"""Platform pinning for virtual-mesh (CPU) runs, and the compile cache.
 
 The multi-chip paths are exercised on N virtual CPU devices
 (``--xla_force_host_platform_device_count``), which requires two things to
@@ -6,12 +6,10 @@ happen *before any jax backend initializes*:
 
 * the forced host device count must be in ``XLA_FLAGS`` (XLA parses it once,
   at CPU-client creation), and
-* the default platform must be pinned to ``cpu`` at BOTH the env level
-  (``JAX_PLATFORMS``) and the config level (``jax.config``) — a TPU plugin
-  that pins ``jax_platforms`` at config level would otherwise override the
-  env var, and an eager array created on the default backend would try to
-  initialize the TPU client (which must never happen on a host whose
-  libtpu/driver is broken: the CPU mesh does not need it).
+* the default platform must be pinned to ``cpu`` (``JAX_PLATFORMS`` and
+  ``jax.config``), so an eager array created on the default backend never
+  initializes a TPU client the CPU mesh does not need — and that another
+  process may hold.
 
 The driver entry point and the scaling scripts share this logic; keep fixes
 here so they reach all of them.  Two sites intentionally differ:
@@ -29,6 +27,32 @@ import re
 from typing import Iterator, List
 
 _COUNT_RE = re.compile(r"--xla_force_host_platform_device_count=(\d+)")
+
+#: the fixed compile-cache directory when JAX_COMPILATION_CACHE_DIR is unset
+#: (the path is part of the cache key, so it must not move between runs)
+REPO_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory (jax reads
+    it itself) and no other is set; otherwise the fixed repo-local
+    :data:`REPO_COMPILE_CACHE`.  Every compile is cached, however short.
+    Entry points call this (chip_smoke.py, bench.py's worker, the demos);
+    the package import and the tests never do — the recompile-sentinel
+    tests count real compiles."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = REPO_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def pin_cpu_platform(n_devices: int) -> List["object"]:

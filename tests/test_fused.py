@@ -46,6 +46,8 @@ def _session(layout="padded", static_rounds=False, num_docs=6, fused=True,
         round_map_capacity=caps[3],
         static_rounds=static_rounds,
         layout=layout,
+        # a page must divide the tiny slot capacity
+        **({} if layout == "padded" else {"page_size": 64}),
     )
     s.fused_pipeline = fused
     # narrow fuse window: drains split into SEVERAL staged batches (more

@@ -285,6 +285,7 @@ def test_streaming_ragged_overflow_parity():
         s = StreamingMerge(
             num_docs=3, actors=ACTORS, slot_capacity=64, mark_capacity=16,
             tomb_capacity=16, layout=layout,
+            **({} if layout == "padded" else {"page_size": 64}),
         )
         s.ingest_frames((d, arr[d][0]) for d in range(3))
         s.drain()

@@ -171,23 +171,24 @@ import logging
 
 from peritext_tpu.obs.sentinel import _COMPILE_MSG_RE
 
-#: VERBATIM record messages captured from the current jax pin (0.4.37,
-#: CPU backend, jax_log_compiles=True) — see the emitting sites in
+#: VERBATIM record messages captured from the installed jax (0.9.0, CPU
+#: backend, jax_log_compiles=True) — see the emitting sites in
 #: jax._src.interpreters.pxla / jax._src.dispatch.  If a jax upgrade
 #: changes these shapes, re-capture and extend; the sentinel must keep
-#: counting exactly the "Compiling <site>" records.
-VERBATIM_JAX_0_4_37 = [
+#: counting exactly the "Compiling jit(<site>)" records.
+VERBATIM_JAX = [
     ("Finished tracing + transforming convert_element_type for pjit "
-     "in 0.000578880 sec", None),
-    ("Compiling convert_element_type with global shapes and types "
-     "[ShapedArray(float32[])]. Argument mapping: (UnspecifiedValue,).",
+     "in 0.008886576 sec", None),
+    ("Compiling jit(convert_element_type) with global shapes and types "
+     "(ShapedArray(float32[]),). Argument mapping: (UnspecifiedValue,).",
      "convert_element_type"),
     ("Finished jaxpr to MLIR module conversion jit(convert_element_type) "
-     "in 0.026105642 sec", None),
+     "in 0.066748619 sec", None),
     ("Finished XLA compilation of jit(convert_element_type) "
-     "in 0.014521360 sec", None),
-    ("Compiling f with global shapes and types [ShapedArray(float32[3])]. "
-     "Argument mapping: (UnspecifiedValue,).", "f"),
+     "in 0.008085012 sec", None),
+    ("Compiling jit(f) with global shapes and types "
+     "(ShapedArray(float32[3]),). Argument mapping: (UnspecifiedValue,).",
+     "f"),
     ("Finished tracing + transforming multiply for pjit in 0.001347542 sec",
      None),
 ]
@@ -197,11 +198,11 @@ VERBATIM_JAX_0_4_37 = [
 #: Compiling line (both observed from handlers downstream of other logging
 #: layers)
 HOSTILE_SHAPES = [
-    ("WARNING:2026-08-03 23:17:59,392:jax._src.interpreters.pxla:1906: "
-     "Compiling f with global shapes and types [ShapedArray(float32[3])].",
+    ("WARNING:2026-10-15 03:05:19,712:jax._src.interpreters.pxla:1943: "
+     "Compiling jit(f) with global shapes and types (ShapedArray(float32[3]),).",
      "f"),
     ("Finished tracing + transforming f for pjit in 0.003565311 sec\n"
-     "Compiling f with global shapes and types [ShapedArray(float32[3])]. "
+     "Compiling jit(f) with global shapes and types (ShapedArray(float32[3]),). "
      "Argument mapping: (UnspecifiedValue,).", "f"),
     # prose containing "compilation"/"Recompiling" must NOT count
     ("Finished XLA compilation of jit(f) in 0.081711054 sec", None),
@@ -210,7 +211,7 @@ HOSTILE_SHAPES = [
 
 
 def test_compile_regex_on_verbatim_and_hostile_records():
-    for message, site in VERBATIM_JAX_0_4_37 + HOSTILE_SHAPES:
+    for message, site in VERBATIM_JAX + HOSTILE_SHAPES:
         m = _COMPILE_MSG_RE.search(message)
         if site is None:
             assert m is None, f"false positive on: {message!r}"
@@ -225,13 +226,13 @@ def test_sentinel_counts_prefixed_and_multiline_records():
 
     counters = Counters()
     sentinel = RecompileSentinel(counters=counters)
-    for message, _ in VERBATIM_JAX_0_4_37 + HOSTILE_SHAPES:
+    for message, _ in VERBATIM_JAX + HOSTILE_SHAPES:
         record = logging.LogRecord(
             "jax._src.interpreters.pxla", logging.WARNING, __file__, 1,
             message, None, None,
         )
         sentinel.emit(record)
-    expected_sites = [s for _, s in VERBATIM_JAX_0_4_37 + HOSTILE_SHAPES if s]
+    expected_sites = [s for _, s in VERBATIM_JAX + HOSTILE_SHAPES if s]
     assert sentinel.total == len(expected_sites)
     assert sentinel.counts == {"convert_element_type": 1, "f": 3}
     assert counters.get("jit.compiles_total") == len(expected_sites)

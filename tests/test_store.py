@@ -143,7 +143,7 @@ def test_batch_paged_matches_padded_under_capacity_fallbacks():
         dict(slot_capacity=256, mark_capacity=64, op_capacity=32),
     ):
         p = DocBatch(**kw).merge(wl)
-        q = DocBatch(layout="paged", **kw).merge(wl)
+        q = DocBatch(layout="paged", page_size=64, **kw).merge(wl)
         assert p.spans == q.spans, kw
         assert p.fallback_docs == q.fallback_docs, kw
         assert p.roots == q.roots, kw
@@ -306,8 +306,10 @@ def test_streaming_paged_overflow_routes_to_replay_like_padded():
     arr = _arrival(wl, rounds=1)
 
     def tiny(layout):
+        kw = {} if layout == "padded" else {"page_size": 64}
         s = StreamingMerge(num_docs=3, actors=ACTORS, slot_capacity=64,
-                           mark_capacity=16, tomb_capacity=16, layout=layout)
+                           mark_capacity=16, tomb_capacity=16, layout=layout,
+                           **kw)
         s.ingest_frames((d, arr[d][0]) for d in range(3))
         s.drain()
         return s
