@@ -207,10 +207,12 @@ def run(args) -> dict:
     # back-to-back — the device executes queued programs serially — and
     # sync once, amortizing dispatch latency exactly as a streaming
     # deployment does.
-    from peritext_tpu.observability import profile_trace
+    import contextlib
 
     times = []
-    with profile_trace(args.profile, enabled=args.profile is not None):
+    capture = (jax.profiler.trace(args.profile) if args.profile is not None
+               else contextlib.nullcontext())
+    with capture:
         for _ in range(3):
             t0 = time.perf_counter()
             for _ in range(args.iters):

@@ -146,6 +146,7 @@ class DocBatch:
             insert_capacity=self.op_capacity,
             delete_capacity=self.op_capacity,
             mark_capacity=self.mark_capacity,
+            tracer=self.tracer,
         )
 
     def apply_encoded(self, encoded: EncodedBatch) -> PackedDocs:
@@ -358,53 +359,54 @@ class DocBatch:
         d_total = len(workloads)
         with self.tracer.span("batch.encode") as sp:
             per_doc, fb_encode, actor_tables, attr_tables, map_tables = (
-                encode_doc_streams(workloads)
+                encode_doc_streams(workloads, self.tracer)
             )
-            fb_set = set(fb_encode)
-            # capacity fallback happens HERE, not in pad_doc_streams: group
-            # streams size to the subgroup max (that is the point of the
-            # layout), so the configured capacities act as per-doc fallback
-            # thresholds exactly as they do on the padded path — same docs
-            # fall back under both layouts
-            empty = _EMPTY_STREAMS
-            for d in range(d_total):
-                s = per_doc[d]
-                over = len(s.marks) > self.mark_capacity
-                if self.op_capacity is not None:
-                    over = over or len(s.ins) > self.op_capacity \
-                        or len(s.dels) > self.op_capacity
-                if over:
-                    fb_set.add(d)
-            # two-component size bucketing: page need (inserts drive slots —
-            # the delete/mark/register tables stay dense aux rows) AND a
-            # power-of-two total-op bucket.  The second component matters
-            # below one page: without it every sub-page tweet pads its
-            # streams to the widest tweet's op count, which is most of the
-            # long-tail waste the paged layout exists to kill.  Fallback
-            # docs carry no streams and ride the smallest bucket as no-ops.
-            max_pages = max(1, self.slot_capacity // self.page_size)
-            buckets: Dict[tuple, List[int]] = {}
-            for d in range(d_total):
-                s = empty if d in fb_set else per_doc[d]
-                ops = len(s.ins) + len(s.dels) + len(s.marks) + len(s.maps)
-                g = min(
-                    _pow2(-(-max(1, len(s.ins)) // self.page_size)), max_pages
-                )
-                buckets.setdefault((g, _pow2(max(8, ops))), []).append(d)
-            groups = [(g, np.asarray(buckets[(g, sb)], np.int64))
-                      for g, sb in sorted(buckets)]
-            encs = []
-            for g, docs in groups:
-                local_fb = [i for i, d in enumerate(docs) if int(d) in fb_set]
-                enc_g = pad_doc_streams(
-                    [empty if int(d) in fb_set else per_doc[int(d)]
-                     for d in docs],
-                    local_fb,
-                    [actor_tables[int(d)] for d in docs],
-                    [attr_tables[int(d)] for d in docs],
-                    map_tables=[map_tables[int(d)] for d in docs],
-                )
-                encs.append((g, docs, enc_g))
+            with self.tracer.span("batch.encode.pad"):
+                fb_set = set(fb_encode)
+                # capacity fallback happens HERE, not in pad_doc_streams: group
+                # streams size to the subgroup max (that is the point of the
+                # layout), so the configured capacities act as per-doc fallback
+                # thresholds exactly as they do on the padded path — same docs
+                # fall back under both layouts
+                empty = _EMPTY_STREAMS
+                for d in range(d_total):
+                    s = per_doc[d]
+                    over = len(s.marks) > self.mark_capacity
+                    if self.op_capacity is not None:
+                        over = over or len(s.ins) > self.op_capacity \
+                            or len(s.dels) > self.op_capacity
+                    if over:
+                        fb_set.add(d)
+                # two-component size bucketing: page need (inserts drive slots —
+                # the delete/mark/register tables stay dense aux rows) AND a
+                # power-of-two total-op bucket.  The second component matters
+                # below one page: without it every sub-page tweet pads its
+                # streams to the widest tweet's op count, which is most of the
+                # long-tail waste the paged layout exists to kill.  Fallback
+                # docs carry no streams and ride the smallest bucket as no-ops.
+                max_pages = max(1, self.slot_capacity // self.page_size)
+                buckets: Dict[tuple, List[int]] = {}
+                for d in range(d_total):
+                    s = empty if d in fb_set else per_doc[d]
+                    ops = len(s.ins) + len(s.dels) + len(s.marks) + len(s.maps)
+                    g = min(
+                        _pow2(-(-max(1, len(s.ins)) // self.page_size)), max_pages
+                    )
+                    buckets.setdefault((g, _pow2(max(8, ops))), []).append(d)
+                groups = [(g, np.asarray(buckets[(g, sb)], np.int64))
+                          for g, sb in sorted(buckets)]
+                encs = []
+                for g, docs in groups:
+                    local_fb = [i for i, d in enumerate(docs) if int(d) in fb_set]
+                    enc_g = pad_doc_streams(
+                        [empty if int(d) in fb_set else per_doc[int(d)]
+                         for d in docs],
+                        local_fb,
+                        [actor_tables[int(d)] for d in docs],
+                        [attr_tables[int(d)] for d in docs],
+                        map_tables=[map_tables[int(d)] for d in docs],
+                    )
+                    encs.append((g, docs, enc_g))
         stats.encode_seconds = sp.duration
 
         try:
@@ -611,27 +613,28 @@ class DocBatch:
         d_total = len(workloads)
         with self.tracer.span("batch.encode") as sp:
             per_doc, fb_encode, actor_tables, attr_tables, map_tables = (
-                encode_doc_streams(workloads)
+                encode_doc_streams(workloads, self.tracer)
             )
-            fb_set = set(fb_encode)
-            # per-doc capacity fallback thresholds: identical to the paged
-            # path so the same docs fall back under every layout
-            for d in range(d_total):
-                s = per_doc[d]
-                over = len(s.marks) > self.mark_capacity
-                if self.op_capacity is not None:
-                    over = over or len(s.ins) > self.op_capacity \
-                        or len(s.dels) > self.op_capacity
-                if over:
-                    fb_set.add(d)
-            enc = pad_doc_streams(
-                [_EMPTY_STREAMS if d in fb_set else per_doc[d]
-                 for d in range(d_total)],
-                sorted(fb_set),
-                actor_tables,
-                attr_tables,
-                map_tables=map_tables,
-            )
+            with self.tracer.span("batch.encode.pad"):
+                fb_set = set(fb_encode)
+                # per-doc capacity fallback thresholds: identical to the paged
+                # path so the same docs fall back under every layout
+                for d in range(d_total):
+                    s = per_doc[d]
+                    over = len(s.marks) > self.mark_capacity
+                    if self.op_capacity is not None:
+                        over = over or len(s.ins) > self.op_capacity \
+                            or len(s.dels) > self.op_capacity
+                    if over:
+                        fb_set.add(d)
+                enc = pad_doc_streams(
+                    [_EMPTY_STREAMS if d in fb_set else per_doc[d]
+                     for d in range(d_total)],
+                    sorted(fb_set),
+                    actor_tables,
+                    attr_tables,
+                    map_tables=map_tables,
+                )
         stats.encode_seconds = sp.duration
 
         try:

@@ -26,9 +26,20 @@ _BUILD_DIR = Path(__file__).parent / "_build"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_build_error: Optional[str] = None
+
+
+def build_error() -> Optional[str]:
+    """Why the last build of the library failed, or None."""
+    return _build_error
 
 
 def _compile() -> Optional[Path]:
+    """Build the library unless the build for this source exists; the
+    build runs under a ``native.build`` span of the process tracer."""
+    global _build_error
+    from ..obs import GLOBAL_TRACER
+
     source = _SRC.read_bytes()
     tag = hashlib.sha256(source).hexdigest()[:16]
     out = _BUILD_DIR / f"libptnative-{tag}.so"
@@ -43,13 +54,16 @@ def _compile() -> Optional[Path]:
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
         str(_SRC), "-o", str(tmp),
     ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, out)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    finally:
-        tmp.unlink(missing_ok=True)
+    with GLOBAL_TRACER.span("native.build", source=_SRC.name) as sp:
+        try:
+            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            os.replace(tmp, out)
+        except (OSError, subprocess.SubprocessError) as exc:
+            stderr = (getattr(exc, "stderr", None) or b"").decode("utf-8", "replace")
+            _build_error = sp.args["error"] = f"{exc} {stderr.strip()[-2000:]}".strip()
+            return None
+        finally:
+            tmp.unlink(missing_ok=True)
     return out
 
 

@@ -79,7 +79,7 @@ from .devprof import (
     note_jit_dispatch,
     occupancy_key,
 )
-from .events import EventLog, profile_trace
+from .events import EventLog
 from .histograms import (
     GLOBAL_HISTOGRAMS,
     Histogram,
@@ -161,7 +161,6 @@ __all__ = [
     "merge_traces",
     "note_jit_dispatch",
     "occupancy_key",
-    "profile_trace",
     "prometheus_text",
     "replay_segments",
 ]

@@ -1,14 +1,13 @@
-"""Structured event logging and JAX profiler hooks."""
+"""Structured event logging."""
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 from pathlib import Path
-from typing import Any, Dict, IO, Iterator, Optional
+from typing import Any, Dict, IO, Optional
 
 
 class EventLog:
@@ -84,28 +83,3 @@ class EventLog:
 
     def __exit__(self, *exc_info) -> None:
         self.close()
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str | Path, enabled: bool = True) -> Iterator[None]:
-    """Capture a JAX profiler trace (viewable in TensorBoard / Perfetto) for
-    the enclosed block.  Silently degrades to a no-op if the profiler is
-    unavailable on the current platform."""
-    if not enabled:
-        yield
-        return
-    try:
-        import jax
-
-        jax.profiler.start_trace(str(log_dir))
-        started = True
-    except Exception:  # graftlint: boundary(profiler availability is platform-defined; tracing must never fail the traced workload)
-        started = False
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # graftlint: boundary(stop mirrors start: a torn trace is dropped, never raised into the workload)
-                pass

@@ -62,7 +62,9 @@ class FlightRecorder:
         self.dump_dir = Path(dump_dir) if dump_dir is not None else None
         self.fsync = bool(fsync)
         self.min_dump_interval = float(min_dump_interval)
-        self._lock = threading.Lock()
+        # reentrant: as a tracer sink it may record a host.gc span from a
+        # collection that ran while this thread held the lock
+        self._lock = threading.RLock()
         self._ring: deque = deque(maxlen=capacity)
         self._seq = 0
         self._last_auto_dump: Optional[float] = None

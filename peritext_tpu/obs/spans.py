@@ -19,11 +19,19 @@ enabled (bounded buffer, for the Perfetto dump) or has sinks (e.g. a
 :class:`~.recorder.FlightRecorder` ring).  Merge-scope modules never read
 the wall clock themselves — the reads live here, in the observability
 layer, keeping graftlint's PTL006 merge scope clean.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` of its name, so in
+any profiler capture it sits on the ``/host:CPU`` plane beside the device
+planes, on the profiler's own clock.  While :data:`GLOBAL_TRACER` is
+active, every garbage collection becomes a finished ``host.gc`` span
+(``generation`` and ``collected`` as args) through its sinks.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
+import itertools
 import json
 import os
 import socket
@@ -175,7 +183,10 @@ class Tracer:
         # context) must not mint colliding ids, or parent links in a merged
         # trace become ambiguous
         self._id_base = int.from_bytes(os.urandom(6), "big") << 14
-        self._next_id = 1
+        # next() on a count and deque.append are atomic, so minting and
+        # retaining take no lock: record() runs from the collector's
+        # callback, possibly while this thread holds self._lock
+        self._ids = itertools.count(self._id_base + 1)
         self._spans: deque = deque(maxlen=capacity)
         self._sinks: List = []
 
@@ -223,36 +234,52 @@ class Tracer:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
             trace_id, parent_id = self.trace_id, 0
-        with self._lock:
-            span_id = self._id_base + self._next_id
-            self._next_id += 1
-        sp = Span(name, trace_id, span_id, parent_id, self.host,
+        sp = Span(name, trace_id, next(self._ids), parent_id, self.host,
                   dict(args), time.time())
-        t0 = time.perf_counter()
         stack = _stack()
         stack.append(sp)
-        try:
-            yield sp
-        except BaseException as exc:  # graftlint: boundary(annotate the span with the escaping error for the timeline; always re-raised)
-            sp.args.setdefault("error", repr(exc))
-            raise
-        finally:
-            sp.duration = time.perf_counter() - t0
-            if stack and stack[-1] is sp:
-                stack.pop()
-            else:  # pragma: no cover - unbalanced exit (generator misuse)
-                try:
-                    stack.remove(sp)
-                except ValueError:
-                    pass
-            if self.enabled:
-                with self._lock:
-                    self._spans.append(sp)
-            for sink in list(self._sinks):
-                try:
-                    sink(sp)
-                except Exception:  # graftlint: boundary(telemetry sinks must never fail the traced workload)
-                    pass
+        with _annotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield sp
+            except BaseException as exc:  # graftlint: boundary(annotate the span with the escaping error for the timeline; always re-raised)
+                sp.args.setdefault("error", repr(exc))
+                raise
+            finally:
+                sp.duration = time.perf_counter() - t0
+                if stack and stack[-1] is sp:
+                    stack.pop()
+                else:  # pragma: no cover - unbalanced exit (generator misuse)
+                    try:
+                        stack.remove(sp)
+                    except ValueError:
+                        pass
+                self._finish(sp)
+
+    def record(self, name: str, start: float, duration: float, **args) -> Span:
+        """Emit a span for an interval that is already over: ``start`` in
+        epoch seconds, ``duration`` in seconds.  It nests under this
+        thread's innermost open span, and never touches the span stack."""
+        parent = current_span()
+        if parent is not None:
+            trace_id, parent_id = parent.trace_id, parent.span_id
+        else:
+            trace_id, parent_id = self.trace_id, 0
+        sp = Span(name, trace_id, next(self._ids), parent_id, self.host,
+                  args, start)
+        sp.duration = duration
+        self._finish(sp)
+        return sp
+
+    def _finish(self, sp: Span) -> None:
+        """Retain a finished span (when enabled) and hand it to every sink."""
+        if self.enabled:
+            self._spans.append(sp)
+        for sink in list(self._sinks):
+            try:
+                sink(sp)
+            except Exception:  # graftlint: boundary(telemetry sinks must never fail the traced workload)
+                pass
 
     def current_context(self) -> Optional[TraceContext]:
         """The context of this thread's innermost open span, for stamping
@@ -294,6 +321,45 @@ def merge_traces(*traces: Dict) -> Dict:
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, with jax imported on first
+    use; outside a profiler capture it records nothing."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
 #: default process-wide tracer: inactive (spans still measure, nothing is
 #: retained) until a caller enables it or attaches a sink
 GLOBAL_TRACER = Tracer()
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: each collection that starts while ``tracer``
+    is active is recorded as a finished ``host.gc`` span."""
+
+    def __init__(self, tracer: Tracer) -> None:
+        self.tracer = tracer
+        self.ts = 0.0
+        self.t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict) -> None:
+        if phase == "start":
+            if self.tracer.enabled or self.tracer._sinks:
+                self.ts = time.time()
+                self.t0 = time.perf_counter()
+        elif self.t0 is not None:
+            duration = time.perf_counter() - self.t0
+            self.t0 = None
+            self.tracer.record("host.gc", self.ts, duration,
+                               generation=info["generation"],
+                               collected=info["collected"])
+
+
+gc.callbacks.append(_GcSpans(GLOBAL_TRACER))

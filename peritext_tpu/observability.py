@@ -28,7 +28,6 @@ from .obs import (  # noqa: F401
     Tracer,
     health_snapshot,
     merge_traces,
-    profile_trace,
     prometheus_text,
 )
 from .obs.metrics import _HEALTH_PREFIXES  # noqa: F401
@@ -54,6 +53,5 @@ __all__ = [
     "Tracer",
     "health_snapshot",
     "merge_traces",
-    "profile_trace",
     "prometheus_text",
 ]

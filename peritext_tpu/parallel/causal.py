@@ -15,6 +15,7 @@ of retry-until-fixpoint, and it yields the padded batches the TPU wants.
 from __future__ import annotations
 
 import heapq
+import logging
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -22,9 +23,14 @@ import numpy as np
 from .. import native
 from ..core.errors import PeritextError
 from ..core.types import Change, Clock
+from ..obs import GLOBAL_COUNTERS
 
 #: Below this many changes the Python scheduler wins (array setup overhead).
 _NATIVE_THRESHOLD = 64
+
+_log = logging.getLogger(__name__)
+#: whether the fall-back after a failed native build has been logged
+_warned = False
 
 
 def _admissible(change: Change, clock: Clock) -> bool:
@@ -45,12 +51,16 @@ def causal_schedule(
 
     Large sets route through the native C++ scheduler (peritext_tpu/native)
     when it is available; both implementations produce identical output.
+    Each schedule counts under ``causal.schedules.native`` or
+    ``causal.schedules.python`` in ``GLOBAL_COUNTERS``, by the path that ran.
     """
     changes = list(changes)
     if len(changes) >= _NATIVE_THRESHOLD:
         result = _native_schedule(changes, base_clock)
         if result is not None:
+            GLOBAL_COUNTERS.add("causal.schedules.native")
             return result
+    GLOBAL_COUNTERS.add("causal.schedules.python")
     clock: Clock = dict(base_clock or {})
     pending: Dict[Tuple[str, int], Change] = {}
     for ch in changes:
@@ -98,7 +108,13 @@ def _native_schedule(
     """Array form of the schedule for the C++ core (peritext_tpu/native).
     Actor indices are assigned in sorted-string order so the native heap's
     integer ordering reproduces the Python tie-break exactly."""
+    global _warned
     if not native.available():
+        error = native.build_error()
+        if error is not None and not _warned:
+            _warned = True
+            _log.warning("native build failed, causal schedules run in "
+                         "Python: %s", error)
         return None
     actors = sorted(
         {ch.actor for ch in changes} | set(base_clock or {})

@@ -8,7 +8,7 @@ import pytest
 from peritext_tpu.bridge import create_editor, initialize_docs
 from peritext_tpu.bridge.commands import type_text
 from peritext_tpu.core.doc import Doc
-from peritext_tpu.observability import Counters, EventLog, MergeStats, profile_trace
+from peritext_tpu.observability import Counters, EventLog, MergeStats
 from peritext_tpu.parallel.anti_entropy import apply_changes
 from peritext_tpu.parallel.causal import causal_schedule
 from peritext_tpu.parallel.faults import FaultSpec, FaultyPublisher, perturb_delivery
@@ -179,10 +179,3 @@ class TestObservability:
         assert s.apply_seconds > 0
         d = s.to_json()
         assert d["device_ops_per_sec"] > 0
-
-    def test_profile_trace_noop_safe(self, tmp_path):
-        with profile_trace(tmp_path, enabled=False):
-            pass
-        # enabled path must not raise even if profiler unavailable
-        with profile_trace(tmp_path / "t", enabled=True):
-            pass
