@@ -121,6 +121,20 @@ def load() -> Optional[ctypes.CDLL]:
             + [i32p] * 5  # n_ins, n_del, n_mark, n_map, n_admitted
             + [u8p] * 2  # admitted, status
         )
+        lib.pt_encode_batch.restype = None
+        lib.pt_encode_batch.argtypes = (
+            [ctypes.c_int32, i32p, i32p]  # n_docs, doc_ch_off, doc_int_off
+            + [i32p]  # doc_n_actors
+            + [i32p] * 2  # ch_actor, ch_seq
+            + [i32p] * 3  # dep_off, dep_actor, dep_seq
+            + [i32p] * 2  # ops_off, ops
+            + [i32p] * 2  # doc_attr_off, doc_key_off
+            + [i64p, i32p]  # row_off, row_cap
+            + [i32p] * 17  # ins x3, del, marks x8, map stream x5
+            + [i32p] * 3  # counts, order, n_sched
+            + [i32p] * 4  # attr_order, n_attrs, key_order, n_keys
+            + [u8p]  # status
+        )
         lib.pt_scalar_apply.restype = ctypes.c_int64
         lib.pt_scalar_apply.argtypes = [
             i32p, ctypes.c_int64,  # ops, n_ops
@@ -396,6 +410,44 @@ def schedule_split_batch(
         admitted, status,
     )
     return total, n_ins, n_del, n_mark, n_map, n_admitted, admitted, status
+
+
+def encode_batch(
+    columns,  # (doc_ch_off, doc_int_off, doc_n_actors, ch_actor, ch_seq,
+    #            dep_off, dep_actor, dep_seq, ops_off, ops, doc_attr_off, doc_key_off)
+    row_off: np.ndarray,  # (D, 4) int64: first row of each doc's ins/del/mark/map stream
+    row_cap: np.ndarray,  # (D, 4) int32: rows each may hold
+    stream_columns,  # 17 int32 arrays: ins x3, del, MARK_COLS, MAP_STREAM_COLS; filled in place
+):
+    """Schedule and scatter a whole flattened batch in one call (see
+    pt_encode_batch).  Returns ``(counts, order, n_sched, attr_order,
+    n_attrs, key_order, n_keys, status)``, ``counts`` shaped (D, 4), or
+    None when no native library."""
+    lib = load()
+    if lib is None:
+        return None
+    c = lambda a: np.ascontiguousarray(a, np.int32)  # noqa: E731
+    (doc_ch_off, doc_int_off, doc_n_actors, ch_actor, ch_seq,
+     dep_off, dep_actor, dep_seq, ops_off, ops, doc_attr_off, doc_key_off) = columns
+    n_docs = int(doc_ch_off.shape[0]) - 1
+    n_changes = int(doc_ch_off[-1])
+    counts = np.empty((n_docs, 4), np.int32)
+    order = np.empty(n_changes, np.int32)
+    n_sched = np.empty(n_docs, np.int32)
+    attr_order = np.empty(int(doc_attr_off[-1]), np.int32)
+    key_order = np.empty(int(doc_key_off[-1]), np.int32)
+    n_attrs = np.empty(n_docs, np.int32)
+    n_keys = np.empty(n_docs, np.int32)
+    status = np.empty(n_docs, np.uint8)
+    lib.pt_encode_batch(
+        n_docs, c(doc_ch_off), c(doc_int_off), c(doc_n_actors),
+        c(ch_actor), c(ch_seq), c(dep_off), c(dep_actor), c(dep_seq),
+        c(ops_off), c(ops), c(doc_attr_off), c(doc_key_off),
+        np.ascontiguousarray(row_off, np.int64), c(row_cap),
+        *stream_columns,
+        counts, order, n_sched, attr_order, n_attrs, key_order, n_keys, status,
+    )
+    return counts, order, n_sched, attr_order, n_attrs, key_order, n_keys, status
 
 
 def scalar_apply(ops: np.ndarray):

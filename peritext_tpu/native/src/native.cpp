@@ -19,15 +19,151 @@
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
-#include <queue>
+#include <functional>
 #include <string_view>
 #include <unordered_map>
+#include <unordered_set>
 #include <vector>
 
 namespace {
 inline int64_t key_of(int32_t actor, int32_t seq) {
     return (static_cast<int64_t>(actor) << 32) | static_cast<uint32_t>(seq);
 }
+
+// The deterministic causal schedule behind pt_causal_schedule and
+// pt_encode_batch (the C++ twin of parallel/causal.py::causal_schedule).
+// Duplicates of one (actor, seq) and changes at or below the clock are
+// skipped; among ready changes the smallest (actor, seq) goes first.  The
+// scratch (an open-addressing (actor, seq) table, the waiter lists in CSR
+// form, the heap) is reused across calls, so scheduling a batch of
+// documents allocates once.
+class CausalScheduler {
+  public:
+    // Schedules changes [0, n) against base_clock (n_actors entries) and
+    // writes their indices in application order to out_order.  Returns the
+    // number scheduled; *n_pending receives the number of distinct
+    // (actor, seq) pairs above the clock, so a causal gap is
+    // `returned < *n_pending`.  dep_off[i]..dep_off[i + 1] index dep_actor
+    // and dep_seq directly (the offsets need not start at 0).
+    int32_t run(int32_t n, const int32_t* actor, const int32_t* seq,
+                const int32_t* dep_off, const int32_t* dep_actor,
+                const int32_t* dep_seq, int32_t n_actors,
+                const int32_t* base_clock, int32_t* out_order,
+                int32_t* n_pending) {
+        clock_.assign(base_clock, base_clock + n_actors);
+        size_t cap = 16;
+        while (cap < 2 * static_cast<size_t>(n)) cap <<= 1;
+        mask_ = cap - 1;
+        keys_.resize(cap);
+        slots_.assign(cap, -1);
+        pend_.clear();
+        // pending: the first change of each (actor, seq) above the clock
+        for (int32_t i = 0; i < n; ++i) {
+            if (seq[i] <= clock_[actor[i]]) continue;  // already applied
+            const int64_t key = key_of(actor[i], seq[i]);
+            size_t h = slot_of(key);
+            while (slots_[h] >= 0 && keys_[h] != key) h = (h + 1) & mask_;
+            if (slots_[h] >= 0) continue;  // duplicate: first wins
+            keys_[h] = key;
+            slots_[h] = static_cast<int32_t>(pend_.size());
+            pend_.push_back(i);
+        }
+        const int32_t np = static_cast<int32_t>(pend_.size());
+        *n_pending = np;
+
+        // waiters: pending change -> the pending changes blocked on it
+        // (its per-actor successor and those depending on it); a blocker
+        // that is not pending never applies, so its waiters stay stuck
+        edge_from_.clear();
+        edge_to_.clear();
+        for (int32_t p = 0; p < np; ++p) {
+            const int32_t i = pend_[p];
+            if (seq[i] > 1 && clock_[actor[i]] < seq[i] - 1) {
+                add_edge(key_of(actor[i], seq[i] - 1), p);
+            }
+            for (int32_t d = dep_off[i]; d < dep_off[i + 1]; ++d) {
+                if (dep_actor[d] != actor[i] && clock_[dep_actor[d]] < dep_seq[d]) {
+                    add_edge(key_of(dep_actor[d], dep_seq[d]), p);
+                }
+            }
+        }
+        w_off_.assign(np + 1, 0);
+        for (int32_t b : edge_from_) ++w_off_[b + 1];
+        for (int32_t p = 0; p < np; ++p) w_off_[p + 1] += w_off_[p];
+        w_list_.resize(edge_to_.size());
+        w_fill_.assign(w_off_.begin(), w_off_.end() - 1);
+        for (size_t e = 0; e < edge_from_.size(); ++e) {
+            w_list_[w_fill_[edge_from_[e]]++] = edge_to_[e];
+        }
+
+        auto admissible = [&](int32_t i) -> bool {
+            if (seq[i] != clock_[actor[i]] + 1) return false;
+            for (int32_t d = dep_off[i]; d < dep_off[i + 1]; ++d) {
+                if (clock_[dep_actor[d]] < dep_seq[d]) return false;
+            }
+            return true;
+        };
+        // min-heap over (actor, seq): smallest ready first == Python
+        using HeapKey = std::pair<int64_t, int32_t>;  // (key, pending id)
+        const auto later = std::greater<HeapKey>();
+        heap_.clear();
+        for (int32_t p = 0; p < np; ++p) {
+            const int32_t i = pend_[p];
+            if (admissible(i)) heap_.emplace_back(key_of(actor[i], seq[i]), p);
+        }
+        std::make_heap(heap_.begin(), heap_.end(), later);
+        done_.assign(np, 0);
+        int32_t count = 0;
+        while (!heap_.empty()) {
+            std::pop_heap(heap_.begin(), heap_.end(), later);
+            const int32_t p = heap_.back().second;
+            heap_.pop_back();
+            if (done_[p]) continue;  // woken more than once
+            done_[p] = 1;
+            const int32_t i = pend_[p];
+            out_order[count++] = i;
+            clock_[actor[i]] = seq[i];
+            for (int32_t w = w_off_[p]; w < w_off_[p + 1]; ++w) {
+                const int32_t q = w_list_[w];
+                const int32_t j = pend_[q];
+                if (!done_[q] && admissible(j)) {
+                    heap_.emplace_back(key_of(actor[j], seq[j]), q);
+                    std::push_heap(heap_.begin(), heap_.end(), later);
+                }
+            }
+        }
+        return count;
+    }
+
+  private:
+    size_t slot_of(int64_t key) const {
+        return static_cast<size_t>(
+                   (static_cast<uint64_t>(key) * 0x9E3779B97F4A7C15ull) >> 17) &
+               mask_;
+    }
+    int32_t find(int64_t key) const {
+        size_t h = slot_of(key);
+        while (slots_[h] >= 0) {
+            if (keys_[h] == key) return slots_[h];
+            h = (h + 1) & mask_;
+        }
+        return -1;
+    }
+    void add_edge(int64_t blocker, int32_t waiter) {
+        const int32_t b = find(blocker);
+        if (b < 0) return;
+        edge_from_.push_back(b);
+        edge_to_.push_back(waiter);
+    }
+
+    size_t mask_ = 0;
+    std::vector<int32_t> clock_;
+    std::vector<int64_t> keys_;
+    std::vector<int32_t> slots_, pend_;
+    std::vector<int32_t> edge_from_, edge_to_, w_off_, w_fill_, w_list_;
+    std::vector<uint8_t> done_;
+    std::vector<std::pair<int64_t, int32_t>> heap_;
+};
 
 // ---- wire v2 change/op walk (codec.py is the format's reference) ---------
 //
@@ -437,65 +573,10 @@ int32_t pt_causal_schedule(int32_t n, const int32_t* actor, const int32_t* seq,
                            const int32_t* dep_off, const int32_t* dep_actor,
                            const int32_t* dep_seq, int32_t n_actors,
                            const int32_t* base_clock, int32_t* out_order) {
-    std::vector<int32_t> clock(base_clock, base_clock + n_actors);
-    std::unordered_map<int64_t, int32_t> pending;  // (actor,seq) -> change idx
-    pending.reserve(static_cast<size_t>(n) * 2);
-
-    for (int32_t i = 0; i < n; ++i) {
-        if (seq[i] <= clock[actor[i]]) continue;           // already applied
-        pending.emplace(key_of(actor[i], seq[i]), i);      // first wins (dup skip)
-    }
-
-    auto admissible = [&](int32_t i) -> bool {
-        if (seq[i] != clock[actor[i]] + 1) return false;
-        for (int32_t d = dep_off[i]; d < dep_off[i + 1]; ++d) {
-            if (clock[dep_actor[d]] < dep_seq[d]) return false;
-        }
-        return true;
-    };
-
-    // waiters: blocker (actor, seq) -> change indices waiting on it
-    std::unordered_map<int64_t, std::vector<int32_t>> waiters;
-    waiters.reserve(pending.size());
-    for (const auto& [key, i] : pending) {
-        if (seq[i] > 1 && clock[actor[i]] < seq[i] - 1) {
-            waiters[key_of(actor[i], seq[i] - 1)].push_back(i);
-        }
-        for (int32_t d = dep_off[i]; d < dep_off[i + 1]; ++d) {
-            if (dep_actor[d] != actor[i] && clock[dep_actor[d]] < dep_seq[d]) {
-                waiters[key_of(dep_actor[d], dep_seq[d])].push_back(i);
-            }
-        }
-    }
-
-    // min-heap over (actor, seq): smallest ready first == Python determinism
-    using HeapKey = std::pair<int64_t, int32_t>;  // (key, change idx)
-    std::priority_queue<HeapKey, std::vector<HeapKey>, std::greater<HeapKey>> ready;
-    for (const auto& [key, i] : pending) {
-        if (admissible(i)) ready.emplace(key, i);
-    }
-
-    int32_t count = 0;
-    while (!ready.empty()) {
-        auto [key, i] = ready.top();
-        ready.pop();
-        auto it = pending.find(key);
-        if (it == pending.end()) continue;  // woken more than once
-        pending.erase(it);
-        out_order[count++] = i;
-        clock[actor[i]] = seq[i];
-        auto w = waiters.find(key);
-        if (w != waiters.end()) {
-            for (int32_t j : w->second) {
-                auto pj = pending.find(key_of(actor[j], seq[j]));
-                if (pj != pending.end() && admissible(j)) {
-                    ready.emplace(key_of(actor[j], seq[j]), j);
-                }
-            }
-            waiters.erase(w);
-        }
-    }
-    return count;
+    CausalScheduler scheduler;
+    int32_t n_pending = 0;
+    return scheduler.run(n, actor, seq, dep_off, dep_actor, dep_seq, n_actors,
+                         base_clock, out_order, &n_pending);
 }
 
 // Zigzag-varint encode int32 stream into out (capacity cap bytes).
@@ -891,6 +972,185 @@ int32_t pt_schedule_split_batch(
         total_admitted += nch;
     }
     return total_admitted;
+}
+
+// ---------------------------------------------------------------------------
+// pt_encode_batch — the whole of DocBatch's host encode after the Python
+// flatten (ops/encode.py): one call schedules every document's changes and
+// scatters their ops into the split streams.
+//
+// Each op is one row of the pt_parse_changes columns with its trailing
+// zero columns dropped, built from Change objects: kind 0 insert (c0-c4),
+// 1 delete (c0-c3), 2 mark (c0-c9), 6 map-register op (c0-c5, c3 its key
+// string), 7 makeList (c0-c3, c3 its key string).  ops_off counts rows
+// per change; doc d's rows start at int doc_int_off[d] of ops.  String
+// columns (c3 of kinds 6/7, c5 of a VK_STR map op minus 1, c9 of a mark
+// minus 1) hold batch-wide ids into the flatten's string lists; each
+// doc's lie in doc_attr_off / doc_key_off.
+//
+// Per doc d (changes doc_ch_off[d] .. doc_ch_off[d + 1], actor indices
+// below doc_n_actors[d]):
+//  1. schedule exactly as pt_causal_schedule (CausalScheduler) from a zero
+//     clock; the order, as indices local to the doc, goes to
+//     order[doc_ch_off[d] ..], its length to n_sched[d];
+//  2. walk the scheduled ops through encode.py::encode_doc's state
+//     machine (text list from its makeList, known child maps, first
+//     failure ends the doc) and write each stream row at
+//     row_off[d * 4 + s] (s: 0 ins, 1 del, 2 mark, 3 map), at most
+//     row_cap[d * 4 + s] of them;
+//  3. renumber the doc's strings in first-use order (Interner ids from 1):
+//     m_attr, p_key and VK_STR p_val hold the ids, attr_order / key_order
+//     (at the doc's string offsets) the batch-wide ids in that order,
+//     n_attrs / n_keys their counts.
+// counts[d * 4 + s] receives the rows each stream needs.
+// status[d]: 0 encoded; 1 split it in Python (encode_doc falls back where
+// the walk stopped); 2 causal gap;
+// 3 encoded but over a row_cap (the capacity fallback; counts and string
+// orders are still complete).  Docs with status other than 0 have every
+// stream row zeroed.
+void pt_encode_batch(
+    int32_t n_docs, const int32_t* doc_ch_off, const int32_t* doc_int_off,
+    const int32_t* doc_n_actors,
+    const int32_t* ch_actor, const int32_t* ch_seq,
+    const int32_t* dep_off, const int32_t* dep_actor, const int32_t* dep_seq,
+    const int32_t* ops_off, const int32_t* ops,
+    const int32_t* doc_attr_off, const int32_t* doc_key_off,
+    const int64_t* row_off, const int32_t* row_cap,
+    int32_t* ins_ref, int32_t* ins_op, int32_t* ins_char,
+    int32_t* del_target,
+    int32_t* m_action, int32_t* m_type, int32_t* m_sk, int32_t* m_se,
+    int32_t* m_ek, int32_t* m_ee, int32_t* m_op, int32_t* m_attr,
+    int32_t* p_obj, int32_t* p_key, int32_t* p_op, int32_t* p_kind,
+    int32_t* p_val,
+    int32_t* counts, int32_t* order, int32_t* n_sched,
+    int32_t* attr_order, int32_t* n_attrs, int32_t* key_order, int32_t* n_keys,
+    uint8_t* status) {
+    constexpr int32_t kVkStr = 1, kVkObj = 6, kVkText = 7;  // packed.VK_*
+    constexpr int32_t kRowWidth[8] = {5, 4, 10, 0, 0, 0, 6, 4};  // by kind
+    CausalScheduler scheduler;
+    std::vector<int32_t> zero_clock;
+    std::vector<int64_t> row_start;
+    std::vector<int32_t> attr_id(doc_attr_off[n_docs], 0);
+    std::vector<int32_t> key_id(doc_key_off[n_docs], 0);
+    std::unordered_set<int32_t> map_objs;
+
+    for (int32_t d = 0; d < n_docs; ++d) {
+        const int32_t lo = doc_ch_off[d], n = doc_ch_off[d + 1] - lo;
+        zero_clock.assign(doc_n_actors[d], 0);
+        int32_t n_pending = 0;
+        const int32_t count = scheduler.run(
+            n, ch_actor + lo, ch_seq + lo, dep_off + lo, dep_actor, dep_seq,
+            doc_n_actors[d], zero_clock.data(), order + lo, &n_pending);
+        n_sched[d] = count;
+        int32_t* cnt = counts + static_cast<int64_t>(d) * 4;
+        cnt[0] = cnt[1] = cnt[2] = cnt[3] = 0;
+        n_attrs[d] = n_keys[d] = 0;
+        if (count < n_pending) { status[d] = 2; continue; }
+
+        const int64_t* off = row_off + static_cast<int64_t>(d) * 4;
+        const int32_t* cap = row_cap + static_cast<int64_t>(d) * 4;
+        int32_t ci = 0, cd = 0, cm = 0, cp = 0, na = 0, nk = 0;
+        auto intern_attr = [&](int32_t g) {
+            if (!attr_id[g]) {
+                attr_id[g] = ++na;
+                attr_order[doc_attr_off[d] + na - 1] = g;
+            }
+            return attr_id[g];
+        };
+        auto intern_key = [&](int32_t g) {
+            if (!key_id[g]) {
+                key_id[g] = ++nk;
+                key_order[doc_key_off[d] + nk - 1] = g;
+            }
+            return key_id[g];
+        };
+        // where each of the doc's rows starts
+        bool has_text = false, redo = false;
+        const int32_t row0 = ops_off[lo];
+        row_start.resize(ops_off[lo + n] - row0);
+        for (int64_t i = 0, p = doc_int_off[d]; i < static_cast<int64_t>(row_start.size()); ++i) {
+            row_start[i] = p;
+            const int32_t kind = ops[p];
+            if (kind < 0 || kind >= 8 || !kRowWidth[kind]) { redo = true; break; }
+            p += kRowWidth[kind];
+        }
+        int32_t text_obj = 0;
+        map_objs.clear();
+        for (int32_t k = 0; k < count && !redo; ++k) {
+            const int32_t c = lo + order[lo + k];
+            for (int32_t o = ops_off[c]; o < ops_off[c + 1]; ++o) {
+                const int32_t* r = ops + row_start[o - row0];
+                const int32_t kind = r[0];
+                if (has_text && r[1] == text_obj) {
+                    if (kind == 0) {
+                        if (ci < cap[0]) {
+                            ins_ref[off[0] + ci] = r[3];
+                            ins_op[off[0] + ci] = r[2];
+                            ins_char[off[0] + ci] = r[4];
+                        }
+                        ++ci;
+                    } else if (kind == 1) {
+                        if (cd < cap[1]) del_target[off[1] + cd] = r[3];
+                        ++cd;
+                    } else if (kind == 2) {
+                        if (cm < cap[2]) {
+                            const int64_t at = off[2] + cm;
+                            m_action[at] = r[3]; m_type[at] = r[4];
+                            m_sk[at] = r[5]; m_se[at] = r[6];
+                            m_ek[at] = r[7]; m_ee[at] = r[8];
+                            m_op[at] = r[2];
+                            m_attr[at] = r[9] ? intern_attr(r[9] - 1) : 0;
+                        } else if (r[9]) {
+                            intern_attr(r[9] - 1);
+                        }
+                        ++cm;
+                    } else {
+                        redo = true; break;
+                    }
+                    continue;
+                }
+                // map-object ops: the container must be the root or a
+                // known child map
+                if ((r[1] != -1 && !map_objs.count(r[1])) ||
+                    (kind != 6 && kind != 7) || (kind == 7 && has_text)) {
+                    redo = true; break;
+                }
+                const int32_t key = intern_key(r[3]);
+                int32_t vkind = kVkText, val = r[2];
+                if (kind == 7) {
+                    has_text = true;
+                    text_obj = r[2];
+                } else {
+                    vkind = r[4];
+                    val = r[5];
+                    if (vkind == kVkObj) map_objs.insert(r[2]);
+                    if (vkind == kVkStr) val = intern_key(r[5] - 1);
+                }
+                if (cp < cap[3]) {
+                    const int64_t at = off[3] + cp;
+                    p_obj[at] = r[1]; p_key[at] = key; p_op[at] = r[2];
+                    p_kind[at] = vkind; p_val[at] = val;
+                }
+                ++cp;
+            }
+        }
+        const bool over = ci > cap[0] || cd > cap[1] || cm > cap[2] || cp > cap[3];
+        if (redo || over) {
+            std::memset(ins_ref + off[0], 0, std::min(ci, cap[0]) * sizeof(int32_t));
+            std::memset(ins_op + off[0], 0, std::min(ci, cap[0]) * sizeof(int32_t));
+            std::memset(ins_char + off[0], 0, std::min(ci, cap[0]) * sizeof(int32_t));
+            std::memset(del_target + off[1], 0, std::min(cd, cap[1]) * sizeof(int32_t));
+            for (int32_t* col : {m_action, m_type, m_sk, m_se, m_ek, m_ee, m_op, m_attr})
+                std::memset(col + off[2], 0, std::min(cm, cap[2]) * sizeof(int32_t));
+            for (int32_t* col : {p_obj, p_key, p_op, p_kind, p_val})
+                std::memset(col + off[3], 0, std::min(cp, cap[3]) * sizeof(int32_t));
+        }
+        if (redo) { status[d] = 1; continue; }
+        cnt[0] = ci; cnt[1] = cd; cnt[2] = cm; cnt[3] = cp;
+        n_attrs[d] = na;
+        n_keys[d] = nk;
+        status[d] = over ? 3 : 0;
+    }
 }
 
 // ---------------------------------------------------------------------------

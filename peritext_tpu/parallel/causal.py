@@ -102,19 +102,28 @@ def causal_schedule(
     return out, stuck
 
 
+def native_loaded() -> bool:
+    """Whether the native core is loaded.  The first time a failed build
+    leaves it unloaded, the compiler's error is logged once: schedules and
+    the batch encode (ops/encode.py) then run in Python."""
+    global _warned
+    if native.available():
+        return True
+    error = native.build_error()
+    if error is not None and not _warned:
+        _warned = True
+        _log.warning("native build failed, causal schedules run in "
+                     "Python: %s", error)
+    return False
+
+
 def _native_schedule(
     changes: List[Change], base_clock: Optional[Clock]
 ) -> Optional[Tuple[List[Change], List[Change]]]:
     """Array form of the schedule for the C++ core (peritext_tpu/native).
     Actor indices are assigned in sorted-string order so the native heap's
     integer ordering reproduces the Python tie-break exactly."""
-    global _warned
-    if not native.available():
-        error = native.build_error()
-        if error is not None and not _warned:
-            _warned = True
-            _log.warning("native build failed, causal schedules run in "
-                         "Python: %s", error)
+    if not native_loaded():
         return None
     actors = sorted(
         {ch.actor for ch in changes} | set(base_clock or {})

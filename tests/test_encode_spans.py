@@ -53,8 +53,7 @@ def _mixed_workloads():
 
 
 #: name -> (workloads, capacities, recorded digest, recorded fallback docs);
-#: the digests were recorded from the per-doc encode loop before it was
-#: split into stage-major passes
+#: the digests were recorded from the per-doc Python encode loop
 ENCODE_CASES = {
     "seed7": (
         lambda: generate_workload(seed=7, num_docs=12, ops_per_doc=60), {},
@@ -100,8 +99,35 @@ def sink():
         GLOBAL_TRACER.remove_sink(s)
 
 
-@pytest.mark.parametrize("layout", ["padded", "paged", "ragged"])
-def test_merge_opens_encode_stage_spans_under_encode(layout):
+needs_native = pytest.mark.skipif(not native.available(), reason="needs the native core")
+LAYOUTS = ["padded", "paged", "ragged"]
+#: the encode path as a test parameter: the native one under the plain id,
+#: the per-doc Python one (a failed native build) under "<id>-python"
+PATHS = [pytest.param("native", id="native", marks=needs_native),
+         pytest.param("python", id="python")]
+
+#: the encode's stage spans (names, doc args) for 3 docs, by path and
+#: layout.  With the native core: a flatten (split) per doc, the allocation
+#: (pad), one schedule and scatter (sort) and the tables (pad), and the
+#: paged and ragged layouts pad their groups after that.  In Python: a sort
+#: then a split per doc, then one pad.
+_NATIVE_PADDED = ([STAGES[1]] * 3 + [STAGES[2], STAGES[0], STAGES[2]],
+                  [0, 1, 2, None, None, None])
+_NATIVE_GROUPED = (_NATIVE_PADDED[0] + [STAGES[2]], _NATIVE_PADDED[1] + [None])
+_PYTHON = (STAGES[:2] * 3 + STAGES[2:], [0, 0, 1, 1, 2, 2, None])
+STAGE_SPANS = {
+    "native": {"padded": _NATIVE_PADDED, "paged": _NATIVE_GROUPED,
+               "ragged": _NATIVE_GROUPED},
+    "python": dict.fromkeys(LAYOUTS, _PYTHON),
+}
+
+
+def _use_path(path, monkeypatch):
+    if path == "python":
+        monkeypatch.setattr(native, "available", lambda: False)
+
+
+def _merge_stages(layout):
     sink = _Sink()
     tracer = Tracer(host="encode-stages")
     tracer.add_sink(sink)
@@ -110,26 +136,55 @@ def test_merge_opens_encode_stage_spans_under_encode(layout):
              page_size=32, jit=False, tracer=tracer).merge(workloads)
     (encode,) = sink.named("batch.encode")
     stages = [s for s in sink.spans if s.name.startswith("batch.encode.")]
-    # a sort then a split for each doc, in doc order, then one pad
-    assert [s.name for s in stages] == STAGES[:2] * 3 + STAGES[2:]
-    assert [s.args.get("doc") for s in stages] == [0, 0, 1, 1, 2, 2, None]
     assert all(s.parent_id == encode.span_id for s in stages)
     assert sum(s.duration for s in stages) <= encode.duration
+    return [s.name for s in stages], [s.args.get("doc") for s in stages]
 
 
-def test_stage_spans_carry_counts():
+@pytest.mark.parametrize("layout,path", [
+    *(pytest.param(layout, "native", id=layout, marks=needs_native) for layout in LAYOUTS),
+    *(pytest.param(layout, "python", id=f"{layout}-python") for layout in LAYOUTS),
+])
+def test_merge_opens_encode_stage_spans_under_encode(layout, path, monkeypatch):
+    _use_path(path, monkeypatch)
+    assert _merge_stages(layout) == STAGE_SPANS[path][layout]
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_stage_spans_carry_counts(path, monkeypatch):
+    _use_path(path, monkeypatch)
     sink = _Sink()
     tracer = Tracer(host="encode-counts")
     tracer.add_sink(sink)
     workloads = generate_workload(seed=7, num_docs=3, ops_per_doc=30)
     encode_workloads(workloads, tracer=tracer)
     sorts, splits = sink.named(STAGES[0]), sink.named(STAGES[1])
-    for d, w in enumerate(workloads):
-        assert sorts[d].args == {"doc": d, "changes": sum(map(len, w.values()))}
-        assert splits[d].args == {
-            "doc": d,
-            "ops": sum(len(ch.ops) for log in w.values() for ch in log),
-        }
+    changes = [sum(map(len, w.values())) for w in workloads]
+    ops = [sum(len(ch.ops) for log in w.values() for ch in log) for w in workloads]
+    if path == "native":
+        # one schedule and scatter for the batch, a flatten per doc
+        assert [s.args for s in sorts] == [{"docs": 3, "changes": sum(changes)}]
+        assert [s.args for s in splits] == [
+            {"doc": d, "changes": changes[d], "ops": ops[d], "rows": True}
+            for d in range(3)]
+    else:
+        # a causal sort and a stream split per doc
+        assert [s.args for s in sorts] == [
+            {"doc": d, "changes": changes[d]} for d in range(3)]
+        assert [s.args for s in splits] == [{"doc": d, "ops": ops[d]} for d in range(3)]
+
+
+@needs_native
+def test_batch_encode_counts_a_native_schedule_per_doc(monkeypatch):
+    workloads = generate_workload(seed=7, num_docs=3, ops_per_doc=30)
+    before = (GLOBAL_COUNTERS.get("causal.schedules.native"),
+              GLOBAL_COUNTERS.get("causal.schedules.python"))
+    encode_workloads(workloads)
+    assert (GLOBAL_COUNTERS.get("causal.schedules.native"),
+            GLOBAL_COUNTERS.get("causal.schedules.python")) == (before[0] + 3, before[1])
+    monkeypatch.setattr(native, "available", lambda: False)
+    encode_workloads(workloads)  # 3 docs of under 64 changes each
+    assert GLOBAL_COUNTERS.get("causal.schedules.python") == before[1] + 3
 
 
 def test_gc_collection_is_a_host_gc_span(sink):
@@ -175,7 +230,7 @@ def test_python_scheduler_is_counted(monkeypatch):
     assert GLOBAL_COUNTERS.get("causal.schedules.python") == before + 1
 
 
-@pytest.mark.skipif(not native.available(), reason="needs the native core")
+@needs_native
 def test_native_scheduler_is_counted():
     before = GLOBAL_COUNTERS.get("causal.schedules.native")
     causal.causal_sort(_sortable_changes())
@@ -200,6 +255,30 @@ def test_failed_native_build_is_a_span_and_one_warning(monkeypatch, tmp_path,
         causal.causal_sort(changes)
     (build,) = sink.named("native.build")
     assert "no g++ here" in build.args["error"]
+    warnings = [r for r in caplog.records if r.name == causal.__name__]
+    assert len(warnings) == 1
+    assert "no g++ here" in warnings[0].getMessage()
+
+
+@needs_native
+def test_failed_native_build_encodes_in_python_alike(monkeypatch, tmp_path,
+                                                       caplog):
+    make, caps, digest, fallback = ENCODE_CASES["seed99_mixed"]
+    native_digest = _digest(encode_workloads(make(), **caps))
+
+    def fail(cmd, **kwargs):
+        raise subprocess.CalledProcessError(1, cmd, stderr=b"no g++ here")
+
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path)
+    monkeypatch.setattr(native.subprocess, "run", fail)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_build_error", None)
+    monkeypatch.setattr(causal, "_warned", False)
+    monkeypatch.delenv("PERITEXT_TPU_NO_NATIVE", raising=False)
+    with caplog.at_level(logging.WARNING, logger=causal.__name__):
+        digests = [_digest(encode_workloads(make(), **caps)) for _ in range(2)]
+    assert digests == [native_digest, native_digest] == [digest, digest]
     warnings = [r for r in caplog.records if r.name == causal.__name__]
     assert len(warnings) == 1
     assert "no g++ here" in warnings[0].getMessage()
