@@ -282,7 +282,6 @@ def _batch_ragged_tail(args, ops_dev, state0, apply_jit, sync, oracle,
     from peritext_tpu.store.ragged import ragged_plan
 
     ins_counts = np.count_nonzero(np.asarray(ops_dev[1]), axis=1)
-    del_counts = np.count_nonzero(np.asarray(ops_dev[3]), axis=1)
     max_pages = max(1, s // DEFAULT_PAGE_SIZE)
     need = np.minimum(
         -(-np.maximum(ins_counts, 1) // DEFAULT_PAGE_SIZE), max_pages
@@ -297,14 +296,13 @@ def _batch_ragged_tail(args, ops_dev, state0, apply_jit, sync, oracle,
     store.ensure_rows(rows, ins_counts)
     planes = plan_arrays(ragged_plan(store))
     ic_dev = jnp.asarray(ins_counts, jnp.int32)
-    dc_dev = jnp.asarray(del_counts, jnp.int32)
     pool0 = (store.pool_elem, store.pool_char, store.aux)
 
     def apply_ragged():
         # nodonate: every dispatch re-applies the round to the SAME empty
         # pool, exactly as the padded loop re-applies to state0
         return apply_batch_ragged_jit(
-            *pool0, *planes, ops_dev, ic_dev, dc_dev, donate=False,
+            *pool0, *planes, ops_dev, ic_dev, donate=False,
         )
 
     ns_i = PAGED_AUX_FIELDS.index("num_slots")

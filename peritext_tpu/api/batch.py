@@ -639,37 +639,40 @@ class DocBatch:
 
         try:
             with self.tracer.span("batch.apply") as sp:
-                ins_counts = (np.asarray(enc.ins_op) != 0).sum(axis=1)
-                del_counts = (np.asarray(enc.del_target) != 0).sum(axis=1)
-                max_pages = max(1, self.slot_capacity // self.page_size)
-                page_need = np.minimum(
-                    -(-np.maximum(ins_counts, 1) // self.page_size), max_pages
-                )
-                store = PagedDocStore(
-                    d_total,
-                    slot_capacity=self.slot_capacity,
-                    mark_capacity=self.mark_capacity,
-                    tomb_capacity=enc.del_target.shape[1],
-                    map_capacity=self.map_capacity,
-                    page_size=self.page_size,
-                    # page 0 is the null page; true demand, no bucket round
-                    initial_pages=1 + int(page_need.sum()),
-                )
-                self.last_store = store
-                rows = np.arange(d_total, dtype=np.int64)
-                store.ensure_rows(rows, ins_counts)
-                plan = ragged_plan(store)
-                row_idx, owner, pos_base, prev_page, page_count, page_table = (
-                    plan_arrays(plan)
+                with self.tracer.span("batch.apply.plan"):
+                    ins_counts = (np.asarray(enc.ins_op) != 0).sum(axis=1)
+                    del_counts = (np.asarray(enc.del_target) != 0).sum(axis=1)
+                    max_pages = max(1, self.slot_capacity // self.page_size)
+                    page_need = np.minimum(
+                        -(-np.maximum(ins_counts, 1) // self.page_size), max_pages
+                    )
+                    store = PagedDocStore(
+                        d_total,
+                        slot_capacity=self.slot_capacity,
+                        mark_capacity=self.mark_capacity,
+                        tomb_capacity=enc.del_target.shape[1],
+                        map_capacity=self.map_capacity,
+                        page_size=self.page_size,
+                        # page 0 is the null page; true demand, no bucket round
+                        initial_pages=1 + int(page_need.sum()),
+                    )
+                    self.last_store = store
+                    rows = np.arange(d_total, dtype=np.int64)
+                    store.ensure_rows(rows, ins_counts)
+                    plan = ragged_plan(store)
+                    planes = plan_arrays(plan)
+                    streams = group_stream_arrays(enc, None, d_total)
+                    ins_dev = jnp.asarray(ins_counts, jnp.int32)
+                # the device loops run to the batch's true maxima
+                GLOBAL_COUNTERS.add("merge.ragged_pages", plan.pages_walked)
+                GLOBAL_COUNTERS.add(
+                    "merge.ragged_loop_steps",
+                    int(ins_counts.max(initial=0)) + int(del_counts.max(initial=0)),
                 )
                 store.pool_elem, store.pool_char, store.aux = (
                     apply_batch_ragged_jit(
                         store.pool_elem, store.pool_char, store.aux,
-                        row_idx, owner, pos_base, prev_page, page_count,
-                        page_table,
-                        group_stream_arrays(enc, None, d_total),
-                        jnp.asarray(ins_counts, jnp.int32),
-                        jnp.asarray(del_counts, jnp.int32),
+                        *planes, streams, ins_dev,
                     )
                 )
                 real_ops = int(enc.num_ops.sum())

@@ -682,7 +682,8 @@ def encode_doc_streams(
 
     With the native core loaded this is the columnar encode (spans as in
     :func:`encode_workloads`), each doc given just the rows it may need and
-    its streams read back as rows; without it, the per-doc Python loop.
+    its streams read back as rows under one ``batch.encode.rows`` span;
+    without it, the per-doc Python loop.
 
     Exposed separately so the paged layout (api/batch.py ``layout="paged"``)
     can group docs by size BEFORE padding — each size bucket pads to its own
@@ -692,14 +693,14 @@ def encode_doc_streams(
     if not native_loaded():
         return _encode_doc_streams_python(workloads, tracer)
 
-    def read_back(columns, row_off, counts, fallback, *tables):
-        fb = set(fallback)
+    columns, row_off, counts, fallback, *tables = _encode_columnar(
+        workloads, tracer, None, lambda *parts: parts)
+    fb = set(fallback)
+    with tracer.span("batch.encode.rows", docs=len(workloads)):
         per_doc = [_DocStreams() if d in fb
                    else _streams_at(columns, row_off[d].tolist(), counts[d].tolist())
                    for d in range(len(workloads))]
-        return (per_doc, fallback, *tables)
-
-    return _encode_columnar(workloads, tracer, None, read_back)
+    return (per_doc, fallback, *tables)
 
 
 def encode_workloads(

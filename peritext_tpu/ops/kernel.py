@@ -120,13 +120,15 @@ def _apply_doc(state: PackedDocs, ins_ref, ins_op, ins_char, del_target, mark_ro
 
 
 def _post_insert_doc(state: PackedDocs, del_target, mark_rows, mark_count,
-                     exists=None):
+                     exists=None, skip=None):
     """Phases 2+3 (deletes, marks) for one doc, after the insert phase.
 
     ``exists`` optionally carries a precomputed (KD,) target-exists mask so
     callers whose element planes do NOT live in ``state`` (the ragged pool
     walk, ops/ragged.py) can reuse these phases on a dummy-elem state; with
-    it given, ``state.elem_id`` is never read."""
+    it given, ``state.elem_id`` is never read.  ``skip`` likewise carries a
+    precomputed (KD,) mask of the targets already tombstoned or deleted by
+    an earlier entry of the stream (ops/ragged.py sorts for both)."""
     elem, n, ov = state.elem_id, state.num_slots, state.overflow
 
     # Deletes: validate targets exist, then append to the tombstone table
@@ -136,15 +138,15 @@ def _post_insert_doc(state: PackedDocs, del_target, mark_rows, mark_count,
         exists = jnp.any(elem[:, None] == del_target[None, :], axis=0)  # (KD,)
     # Idempotence: skip targets already tombstoned in the carried-over table
     # AND duplicates within this stream (concurrent deletes of one char).
-    kd = del_target.shape[0]
-    dup_earlier = jnp.any(
-        (del_target[None, :] == del_target[:, None])
-        & (jnp.arange(kd)[:, None] < jnp.arange(kd)[None, :]),
-        axis=0,
-    )
-    already = (
-        jnp.any(state.tomb_id[:, None] == del_target[None, :], axis=0) | dup_earlier
-    ) & live
+    if skip is None:
+        kd = del_target.shape[0]
+        dup_earlier = jnp.any(
+            (del_target[None, :] == del_target[:, None])
+            & (jnp.arange(kd)[:, None] < jnp.arange(kd)[None, :]),
+            axis=0,
+        )
+        skip = jnp.any(state.tomb_id[:, None] == del_target[None, :], axis=0) | dup_earlier
+    already = skip & live
     del_err = jnp.any(live & ~exists)
     keep = live & exists & ~already
     # compact kept targets to a dense prefix so the append is contiguous

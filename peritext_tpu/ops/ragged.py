@@ -20,7 +20,7 @@ Two implementations behind ``resolve_ragged_impl`` (ops/kernel.py):
   plane (``.at[owner].min/max``), and the RGA splice's roll becomes a
   lane shift whose lane-0 value comes through ``prev_page``.  One
   ``lax.fori_loop`` with a TRACED bound = the round's max true insert
-  count; deletes build their target-exists matrix the same way.
+  count.
 * ``"pallas"`` / ``"pallas_interpret"`` — the TPU kernel: grid over docs
   with the page table scalar-prefetched, each doc's true pages gathered
   once into a VMEM window, its true ops applied, pages written back
@@ -33,7 +33,9 @@ is kernel._insert_loop with positions relabeled through ``pos_base``
 (element ids are unique, so the segment min over matches IS the padded
 argmax), the delete/mark/register phases ARE kernel._post_insert_doc /
 _apply_map_doc vmapped over the dense aux rows, with the target-exists
-mask precomputed against pool pages.  tests/test_ragged.py pins the
+and already-deleted masks precomputed by sorting each doc's pages and
+tombstones (O(n log n), where the padded path's pairwise compares would
+cost 1.2e10 pairs for a book-length doc).  tests/test_ragged.py pins the
 equality across every workload family.
 """
 
@@ -141,20 +143,29 @@ def _ragged_insert_lax(pool_elem, pool_char, owner, pos_base, prev_page,
     return lax.fori_loop(0, k_ins, body, (pool_elem, pool_char, n0, ov0))
 
 
-def _ragged_exists_lax(pool_elem, owner, del_target, k_del):
-    """(B+1, KD) bool: does each delete target exist among its doc's pool
-    pages.  One traced-bound fori over the round's max true delete count;
-    columns beyond a doc's own count carry target 0 (dead: the caller's
-    ``live`` mask gates them) so skipping them preserves byte equality."""
-    bp1, kd = del_target.shape
+def _member(sorted_values, x):
+    """Whether each of ``x`` occurs in ``sorted_values`` (both 1-D)."""
+    at = jnp.searchsorted(sorted_values, x)
+    return sorted_values[jnp.minimum(at, sorted_values.shape[0] - 1)] == x
 
-    def body(j, ex):
-        tgt = lax.dynamic_index_in_dim(del_target, j, axis=1, keepdims=False)
-        hit_pg = jnp.any(pool_elem == tgt[owner][:, None], axis=1)  # (N,)
-        col = jnp.zeros((bp1,), bool).at[owner].max(hit_pg)
-        return lax.dynamic_update_index_in_dim(ex, col, j, axis=1)
 
-    return lax.fori_loop(0, k_del, body, jnp.zeros((bp1, kd), bool))
+def _delete_masks(doc_elems, tomb_id, del_target):
+    """One doc's delete-phase masks, by sorting instead of kernel.
+    _post_insert_doc's pairwise compares (which cost KD x S and KD x KD a
+    doc: 1.2e10 pairs for a book-length doc's 77K deletes):
+
+    * ``exists`` (KD,) — the target is among the doc's elements,
+    * ``skip`` (KD,) — the target is already tombstoned, or an earlier
+      entry of the stream deletes it too.
+
+    Dead (zero) targets come out arbitrary; the caller's ``live`` mask
+    gates them, as it gates the pairwise forms."""
+    exists = _member(jnp.sort(doc_elems), del_target)
+    order = jnp.argsort(del_target, stable=True)  # equal targets keep stream order
+    ranked = del_target[order]
+    repeat = jnp.concatenate([jnp.zeros((1,), bool), ranked[1:] == ranked[:-1]])
+    dup_earlier = jnp.zeros(del_target.shape, bool).at[order].set(repeat)
+    return exists, _member(jnp.sort(tomb_id), del_target) | dup_earlier
 
 
 def apply_batch_ragged(
@@ -169,7 +180,6 @@ def apply_batch_ragged(
     page_table,  # (B, max_doc_pages) pool page per doc-page (pallas plane)
     encoded_arrays,  # the apply_batch stream tuple with (B, ...) doc axes
     ins_counts,  # (B,) int32 TRUE per-doc insert counts (data, not shape)
-    del_counts,  # (B,) int32 TRUE per-doc delete counts (data, not shape)
     *,
     ragged_impl: str = "auto",
 ):
@@ -198,16 +208,14 @@ def apply_batch_ragged(
 
     p = pool_elem.shape[1]
     ins_counts = jnp.asarray(ins_counts, jnp.int32)
-    del_counts = jnp.asarray(del_counts, jnp.int32)
     n0 = aux[_NUM_SLOTS][row_idx]
     ov0 = aux[_OVERFLOW][row_idx]
     cap = page_count.astype(jnp.int32) * jnp.int32(p)
-    k_del = jnp.max(del_counts, initial=0)
 
     if impl in ("pallas", "pallas_interpret"):
         from .ragged_pallas import ragged_vmem_ok
 
-        if not ragged_vmem_ok(page_table.shape[1], p, ins_op.shape[1]):
+        if not ragged_vmem_ok(page_table.shape[1], p):
             impl = "lax"
     if impl in ("pallas", "pallas_interpret"):
         from .ragged_pallas import ragged_insert_pallas
@@ -228,19 +236,19 @@ def apply_batch_ragged(
     else:
         raise ValueError(f"unknown ragged_impl: {ragged_impl!r}")
 
-    exists = _ragged_exists_lax(pool_elem, owner, _pad_row(del_target), k_del)
-
     # phases 2-4 run on the dense aux rows exactly as the padded path does
     # (they never touch the element planes: the one elem read — the delete
-    # target-exists scan — was precomputed against pool pages above)
+    # target-exists test — is made here against each doc's pool pages)
     sub = {f: a[row_idx] for f, a in zip(PAGED_AUX_FIELDS, aux)}
     b = ins_ref.shape[0]
+    doc_elems = pool_elem[page_table].reshape(b, -1)  # null page 0 pads
+    exists, skip = jax.vmap(_delete_masks)(doc_elems, sub["tomb_id"], del_target)
     dummy = jnp.zeros((b, 1), jnp.int32)
     state = PackedDocs(elem_id=dummy, char=dummy, **sub)
     state = state._replace(num_slots=n1, overflow=ov1)
     state = jax.vmap(
-        lambda s, d, m, mc, ex: _post_insert_doc(s, d, m, mc, exists=ex)
-    )(state, del_target, marks, mark_count, exists[:b])
+        lambda s, d, m, mc, ex, sk: _post_insert_doc(s, d, m, mc, exists=ex, skip=sk)
+    )(state, del_target, marks, mark_count, exists, skip)
     if maps is not None:
         state = jax.vmap(_apply_map_doc)(
             state, maps["p_obj"], maps["p_key"], maps["p_op"],
@@ -264,7 +272,7 @@ _apply_batch_ragged_jit_nodonate = jax.jit(
 
 def apply_batch_ragged_jit(pool_elem, pool_char, aux, row_idx, owner,
                            pos_base, prev_page, page_count, page_table,
-                           encoded_arrays, ins_counts, del_counts, *,
+                           encoded_arrays, ins_counts, *,
                            ragged_impl: str = "auto",
                            donate: bool | None = None):
     """jit-compiled :func:`apply_batch_ragged`; the pool operands are
@@ -277,7 +285,7 @@ def apply_batch_ragged_jit(pool_elem, pool_char, aux, row_idx, owner,
         donate = resolve_state_donation(pool_elem)
     fn = _apply_batch_ragged_jit if donate else _apply_batch_ragged_jit_nodonate
     args = (pool_elem, pool_char, aux, row_idx, owner, pos_base, prev_page,
-            page_count, page_table, encoded_arrays, ins_counts, del_counts)
+            page_count, page_table, encoded_arrays, ins_counts)
     if GLOBAL_DEVPROF.enabled:
         _note_dispatch("apply_batch_ragged", fn, args,
                        dict(ragged_impl=ragged_impl))
@@ -298,26 +306,18 @@ def plan_arrays(plan):
 
 
 def stream_counts(enc, rows=None):
-    """Host-side ``(ins_counts, del_counts)`` int32 pair for one round's
-    staging buffers: the TRUE per-doc insert / delete counts (restricted
-    to ``rows`` when given).  These are the loop trip counts the ragged
-    program runs — the quantity that makes padded stream slots free.
+    """Host-side int32 TRUE per-doc insert counts of one round's staging
+    buffers (restricted to ``rows`` when given): the loop trip counts the
+    ragged program runs — the quantity that makes padded stream slots free.
 
     Streaming round buffers carry the counts directly; EncodedBatch does
     not, so fall back to counting live stream entries (a live insert has a
-    nonzero op id, a live delete a nonzero target)."""
+    nonzero op id)."""
     import numpy as np
 
     ins = getattr(enc, "ins_count", None)
     if ins is not None:
         ins = np.asarray(ins, np.int32)
-        dels = np.asarray(enc.del_count, np.int32)
     else:
         ins = np.count_nonzero(np.asarray(enc.ins_op), axis=1).astype(np.int32)
-        dels = np.count_nonzero(
-            np.asarray(enc.del_target), axis=1
-        ).astype(np.int32)
-    if rows is not None:
-        ins = ins[rows]
-        dels = dels[rows]
-    return ins, dels
+    return ins if rows is None else ins[rows]

@@ -813,7 +813,6 @@ class RaggedStreamingMerge(PagedStreamingMerge):
             row_idx, owner, pos_base, prev_page, page_count, page_table,
             group_stream_arrays(enc, None, d),
             jnp.asarray(enc.ins_count, jnp.int32),
-            jnp.asarray(enc.del_count, jnp.int32),
         )
         # ragged pays real ops only: no bucket pad rows, no padded slots —
         # capacity IS the real work, so padding_efficiency reads 1.0
@@ -872,7 +871,6 @@ class RaggedStreamingMerge(PagedStreamingMerge):
             (
                 group_stream_arrays(enc, None, d),
                 jnp.asarray(enc.ins_count, jnp.int32),
-                jnp.asarray(enc.del_count, jnp.int32),
             )
             for enc, _ in batch
         )
@@ -946,22 +944,21 @@ class RaggedStreamingMerge(PagedStreamingMerge):
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
-            def body(pool_elem, pool_char, aux, planes, earrays,
-                     ins_counts, del_counts):
+            def body(pool_elem, pool_char, aux, planes, earrays, ins_counts):
                 (row_idx, owner, pos_base, prev_page, page_count,
                  page_table) = jax.tree_util.tree_map(
                     lambda x: x[0], planes)
                 return apply_batch_ragged(
                     pool_elem, pool_char, aux, row_idx, owner, pos_base,
                     prev_page, page_count, page_table, earrays,
-                    ins_counts, del_counts, ragged_impl=impl,
+                    ins_counts, ragged_impl=impl,
                 )
 
             # check_vma=False: pallas_call results carry no varying-axes
             # type; every operand and result is doc-sharded anyway
             wrapped = shard_map(
                 body, mesh=mesh,
-                in_specs=(P(_mesh.DOC_AXIS),) * 7,
+                in_specs=(P(_mesh.DOC_AXIS),) * 6,
                 out_specs=(P(_mesh.DOC_AXIS),) * 3,
                 check_vma=False,
             )
@@ -975,7 +972,7 @@ class RaggedStreamingMerge(PagedStreamingMerge):
         (docs_walked, pages_walked), planes = self._mesh_ragged_planes()
         fn = self._mesh_ragged_fn()
         GLOBAL_COUNTERS.add("streaming.fused_dispatches")
-        for (enc, widths), (earrays, ins_counts, del_counts) in zip(
+        for (enc, widths), (earrays, ins_counts) in zip(
             batch, inputs
         ):
             rows = np.nonzero(enc.num_ops)[0]
@@ -984,11 +981,11 @@ class RaggedStreamingMerge(PagedStreamingMerge):
                 note_jit_dispatch(
                     "apply_batch_ragged.mesh", fn,
                     (store.pool_elem, store.pool_char, store.aux, planes,
-                     earrays, ins_counts, del_counts),
+                     earrays, ins_counts),
                 )
             store.pool_elem, store.pool_char, store.aux = fn(
                 store.pool_elem, store.pool_char, store.aux, planes,
-                earrays, ins_counts, del_counts,
+                earrays, ins_counts,
             )
             self._commit_caps[id(enc)] = real
             if GLOBAL_DEVPROF.enabled:
@@ -1018,7 +1015,7 @@ class RaggedStreamingMerge(PagedStreamingMerge):
         store = self._store
         plan, planes = self._ragged_planes()
         row_idx, owner, pos_base, prev_page, page_count, page_table = planes
-        for (enc, widths), (earrays, ins_counts, del_counts) in zip(
+        for (enc, widths), (earrays, ins_counts) in zip(
             batch, inputs
         ):
             rows = np.nonzero(enc.num_ops)[0]
@@ -1027,7 +1024,7 @@ class RaggedStreamingMerge(PagedStreamingMerge):
                 apply_batch_ragged_jit(
                     store.pool_elem, store.pool_char, store.aux,
                     row_idx, owner, pos_base, prev_page, page_count,
-                    page_table, earrays, ins_counts, del_counts,
+                    page_table, earrays, ins_counts,
                 )
             )
             self._commit_caps[id(enc)] = real

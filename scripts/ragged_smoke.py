@@ -78,7 +78,7 @@ def main() -> int:
         per_doc, fallback, actor_tables, attr_tables, map_tables
     )
     d = enc.ins_ref.shape[0]
-    ins_counts, del_counts = stream_counts(enc)
+    ins_counts = stream_counts(enc)
     oracle = apply_batch_jit(
         empty_docs(d, 512, 128), encoded_arrays_of(enc)
     )
@@ -90,7 +90,7 @@ def main() -> int:
             store.pool_elem, store.pool_char, store.aux,
             *plan_arrays(ragged_plan(store)),
             group_stream_arrays(enc, None, d),
-            jnp.asarray(ins_counts), jnp.asarray(del_counts),
+            jnp.asarray(ins_counts),
             ragged_impl=impl,
         )
         got = store.materialize_rows(rows, bucket_pages=store.max_doc_pages)

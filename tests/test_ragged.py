@@ -54,7 +54,7 @@ def _ragged_vs_padded(workloads, slot_capacity, mark_capacity, page_size, impl):
         per_doc, fallback, actor_tables, attr_tables, map_tables
     )
     d = enc.ins_ref.shape[0]
-    ins_counts, del_counts = stream_counts(enc)
+    ins_counts = stream_counts(enc)
 
     ref = apply_batch_jit(
         empty_docs(d, slot_capacity, mark_capacity), encoded_arrays_of(enc)
@@ -70,7 +70,7 @@ def _ragged_vs_padded(workloads, slot_capacity, mark_capacity, page_size, impl):
         store.pool_elem, store.pool_char, store.aux,
         *plan_arrays(plan),
         group_stream_arrays(enc, None, d),
-        jnp.asarray(ins_counts), jnp.asarray(del_counts),
+        jnp.asarray(ins_counts),
         ragged_impl=impl,
     )
     got = store.materialize_rows(rows, bucket_pages=store.max_doc_pages)
@@ -117,6 +117,25 @@ def test_ragged_apply_overflow(impl):
     _ragged_vs_padded(
         generate_workload(7, num_docs=3, ops_per_doc=90), 64, 64, 32, impl
     )
+
+
+def test_ragged_kernel_streams_in_chunks(monkeypatch):
+    # a stream longer than one SMEM chunk is read a chunk per grid step,
+    # with each doc's count, slot count and overflow carried across them
+    import jax
+
+    from peritext_tpu.ops import ragged_pallas
+
+    monkeypatch.setattr(ragged_pallas, "STREAM_CHUNK", 16)
+    jax.clear_caches()  # the chunk is read when the kernel traces
+    try:
+        w = generate_workload(21, num_docs=3, ops_per_doc=14)
+        w += generate_workload(22, num_docs=2, ops_per_doc=160)
+        _ragged_vs_padded(w, 512, 128, 64, "pallas_interpret")
+        _ragged_vs_padded(generate_workload(23, num_docs=2, ops_per_doc=90),
+                          64, 64, 32, "pallas_interpret")
+    finally:
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("seed", range(4))

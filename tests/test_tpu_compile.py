@@ -67,13 +67,10 @@ def test_pallas_insert_compiles(one_chip, docs, slots, inserts):
     _assert_kernel(compiled)
 
 
-def test_ragged_kernel_compiles_at_8k_docs(one_chip):
-    """Per-doc BlockSpecs of (B, 1)/(B, KI) operands were refused by the
-    chip's compiler; the page table prefetch overflowed SMEM."""
+def _compile_ragged_kernel(one_chip, b, ki, gmax):
     from peritext_tpu.ops.ragged_pallas import ragged_insert_pallas
     from peritext_tpu.store import DEFAULT_PAGE_SIZE
 
-    b, ki, gmax = 8192, 179, 3
     n = 1 + b * gmax
     args = (
         _spec((n, DEFAULT_PAGE_SIZE), one_chip),
@@ -85,6 +82,18 @@ def test_ragged_kernel_compiles_at_8k_docs(one_chip):
         _spec((b, ki), one_chip),
     )
     _assert_kernel(ragged_insert_pallas.lower(*args).compile())
+
+
+def test_ragged_kernel_compiles_at_8k_docs(one_chip):
+    """Per-doc BlockSpecs of (B, 1)/(B, KI) operands were refused by the
+    chip's compiler; the page table prefetch overflowed SMEM."""
+    _compile_ragged_kernel(one_chip, 8192, 179, 3)
+
+
+def test_ragged_kernel_compiles_at_book_length(one_chip):
+    """batch_longdoc's 4 docs of 182,315 inserts: a whole insert stream per
+    doc overflowed SMEM; it is now read a chunk at a time."""
+    _compile_ragged_kernel(one_chip, 4, 182320, 1425)
 
 
 def test_doc_sharded_apply_compiles_on_4_chips(topo):
