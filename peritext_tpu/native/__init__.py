@@ -2,10 +2,13 @@
 
 Compiles ``src/native.cpp`` into a shared library on first use (g++ is in the
 image; there is no pybind11, so the boundary is a plain C ABI bound with
-ctypes) and exposes typed wrappers.  The build is cached next to the source
-keyed by a source hash; set ``PERITEXT_TPU_NO_NATIVE=1`` to force the pure
-Python fallbacks (every native entry point has one — the native layer is an
-accelerator, never a requirement).
+ctypes) and exposes typed wrappers.  ``src/flatten.cpp``, the one walk that
+reads Python objects through the CPython API, is a library of its own
+(:func:`flatten_walker`), so that a missing ``Python.h`` costs only it.
+Each build is cached next to the source keyed by a source hash; set
+``PERITEXT_TPU_NO_NATIVE=1`` to force the pure Python fallbacks (every
+native entry point has one — the native layer is an accelerator, never a
+requirement).
 """
 
 from __future__ import annotations
@@ -14,19 +17,23 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sysconfig
 import threading
 from pathlib import Path
-from typing import Optional
+from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
 _SRC = Path(__file__).parent / "src" / "native.cpp"
+_FLATTEN_SRC = Path(__file__).parent / "src" / "flatten.cpp"
 _BUILD_DIR = Path(__file__).parent / "_build"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 _build_error: Optional[str] = None
+_walker: Optional[Callable] = None
+_walker_tried = False
 
 
 def build_error() -> Optional[str]:
@@ -34,42 +41,43 @@ def build_error() -> Optional[str]:
     return _build_error
 
 
-def _compile() -> Optional[Path]:
-    """Build the library unless the build for this source exists; the
-    build runs under a ``native.build`` span of the process tracer."""
-    global _build_error
+def _compile(src: Path = _SRC, stem: str = "ptnative", flags: Sequence[str] = (),
+             salt: str = "") -> Tuple[Optional[Path], Optional[str]]:
+    """Build ``src`` into ``_build/lib<stem>-<hash>.so`` unless that build
+    exists (the hash covers the source and ``salt``); the build runs under
+    a ``native.build`` span of the process tracer.  Returns the library's
+    path, or None and why the build failed."""
     from ..obs import GLOBAL_TRACER
 
-    source = _SRC.read_bytes()
-    tag = hashlib.sha256(source).hexdigest()[:16]
-    out = _BUILD_DIR / f"libptnative-{tag}.so"
+    tag = hashlib.sha256(src.read_bytes() + salt.encode()).hexdigest()[:16]
+    out = _BUILD_DIR / f"lib{stem}-{tag}.so"
     if out.exists():
-        return out
+        return out, None
     _BUILD_DIR.mkdir(exist_ok=True)
     # Unique tmp name per process: concurrent first-use builds (pytest
     # workers, shared FS) must not interleave writes before the atomic
     # rename installs the hash-keyed artifact.
-    tmp = _BUILD_DIR / f".libptnative-{tag}.{os.getpid()}.tmp"
+    tmp = _BUILD_DIR / f".lib{stem}-{tag}.{os.getpid()}.tmp"
     cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-        str(_SRC), "-o", str(tmp),
+        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", *flags,
+        str(src), "-o", str(tmp),
     ]
-    with GLOBAL_TRACER.span("native.build", source=_SRC.name) as sp:
+    with GLOBAL_TRACER.span("native.build", source=src.name) as sp:
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp, out)
         except (OSError, subprocess.SubprocessError) as exc:
             stderr = (getattr(exc, "stderr", None) or b"").decode("utf-8", "replace")
-            _build_error = sp.args["error"] = f"{exc} {stderr.strip()[-2000:]}".strip()
-            return None
+            sp.args["error"] = f"{exc} {stderr.strip()[-2000:]}".strip()
+            return None, sp.args["error"]
         finally:
             tmp.unlink(missing_ok=True)
-    return out
+    return out, None
 
 
 def load() -> Optional[ctypes.CDLL]:
     """The native library, or None when unavailable/disabled."""
-    global _lib, _tried
+    global _lib, _tried, _build_error
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -78,7 +86,7 @@ def load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("PERITEXT_TPU_NO_NATIVE") == "1":
             return None
-        path = _compile()
+        path, _build_error = _compile()
         if path is None:
             return None
         try:
@@ -159,6 +167,36 @@ def load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return load() is not None
+
+
+def flatten_walker() -> Optional[Callable]:
+    """``pt_flatten_doc`` of ``src/flatten.cpp`` (ops/encode.py's native
+    flatten), or None when it is unavailable or disabled.  It reads Python
+    objects, so it is bound through ``ctypes.PyDLL``, which holds the GIL
+    across the call (``CDLL`` releases it)."""
+    global _walker, _walker_tried
+    if _walker is not None or _walker_tried:
+        return _walker
+    with _lock:
+        if _walker is not None or _walker_tried:
+            return _walker
+        _walker_tried = True
+        if os.environ.get("PERITEXT_TPU_NO_NATIVE") == "1":
+            return None
+        include = sysconfig.get_paths()["include"]
+        path, _ = _compile(_FLATTEN_SRC, "ptflatten", ["-I", include],
+                           salt=f"{sysconfig.get_config_var('SOABI')} {include}")
+        if path is None:
+            return None
+        try:
+            fn = ctypes.PyDLL(str(path)).pt_flatten_doc
+        except (OSError, AttributeError):
+            return None
+        fn.restype = ctypes.py_object
+        fn.argtypes = [ctypes.py_object, ctypes.py_object, ctypes.c_ssize_t,
+                       ctypes.c_ssize_t]
+        _walker = fn
+        return _walker
 
 
 def causal_schedule_indices(

@@ -975,9 +975,10 @@ int32_t pt_schedule_split_batch(
 }
 
 // ---------------------------------------------------------------------------
-// pt_encode_batch — the whole of DocBatch's host encode after the Python
-// flatten (ops/encode.py): one call schedules every document's changes and
-// scatters their ops into the split streams.
+// pt_encode_batch — the whole of DocBatch's host encode after the flatten
+// (flatten.cpp's pt_flatten_doc, or ops/encode.py's _flatten_rows where the
+// walker is not built or declines a doc): one call schedules every
+// document's changes and scatters their ops into the split streams.
 //
 // Each op is one row of the pt_parse_changes columns with its trailing
 // zero columns dropped, built from Change objects: kind 0 insert (c0-c4),

@@ -346,7 +346,9 @@ def _encode_doc_streams_python(workloads, tracer):
 # Each doc's changes are flattened once, in delivery order, into the int
 # columns of native.cpp's pt_encode_batch: a header per change (actor index,
 # seq, deps) and a row per op in the pt_parse_changes column layout, its
-# trailing zero columns dropped, ids packed inline.  One native call then
+# trailing zero columns dropped, ids packed inline.  The flatten is
+# flatten.cpp's walk of the objects where the walker is built and takes the
+# doc, else _flatten_rows; the two give the same columns.  One native call then
 # schedules every doc (the causal sort's exact order) and walks its ops
 # through encode_doc's rules straight into the stream arrays.  Strings (mark
 # attrs, map keys and values) travel as ids into per-batch lists and are
@@ -416,13 +418,14 @@ class _Flat:
             np.asarray(self.doc_key_off, np.int32),
         )
 
-    def add_doc(self, changes, actors, expressed, bounds, heads, deps, rows=None,
+    def add_doc(self, changes, actors, expressed, bounds, heads, deps, rows=b"",
                 attr_strs=(), key_strs=()) -> None:
+        """``heads``, ``deps`` and ``rows``: int32 columns as bytes, in the
+        machine's byte order."""
         self.doc_int_off.append(len(self.ops))
-        self.heads += heads
-        self.deps += deps
-        if rows is not None:
-            self.ops += rows
+        self.heads.frombytes(heads)
+        self.deps.frombytes(deps)
+        self.ops.frombytes(rows)
         self.attr_strs += attr_strs
         self.key_strs += key_strs
         self.changes.append(changes)
@@ -477,7 +480,9 @@ def _flatten_rows(changes: List[Change], flat: _Flat) -> int:
     over its changes; returns its op count.  Raises one of
     ``_UNEXPRESSED`` (and leaves ``flat`` as it was) where the doc needs
     encode_doc: an op id, element or dep of an actor that sent no change, a
-    value the device cannot hold, more actors than ids can pack."""
+    value the device cannot hold, more actors than ids can pack.  The
+    native flatten (``native/src/flatten.cpp``) is its twin, column for
+    column; this runs where that one is not built or declines the doc."""
     actors = sorted({ch.actor for ch in changes})
     if len(actors) > MAX_ACTORS:
         raise _Unexpressed("actors")
@@ -542,25 +547,39 @@ def _flatten_rows(changes: List[Change], flat: _Flat) -> int:
             else:
                 put(_map_row(op, pobj, popid, key_id))
     # array() raises OverflowError for a counter over MAX_CTR
+    heads, deps, rows = (array("i", c).tobytes() for c in (heads, deps, rows))
     flat.add_doc(changes, actors, True,
                  (n_ins, n_del, n_mark, n_ops - n_ins - n_del - n_mark),
-                 array("i", heads), array("i", deps), array("i", rows),
-                 attr_strs, key_strs)
+                 heads, deps, rows, attr_strs, key_strs)
     return n_ops
 
 
-def _flatten_doc(queues: Dict[str, List[Change]], flat: _Flat) -> Tuple[int, int, bool]:
-    """Append one doc to ``flat``, in delivery order (no sort).  Returns its
-    change and op counts, and whether its ops became rows."""
+#: the constants flatten.cpp's walk compares against, in its order
+_WALK_CONSTS = (ROOT, HEAD, MARK_INDEX, _BK, ACTOR_BITS, MAX_ACTORS, MA_ADD, MA_REMOVE,
+                VK_DELETED, VK_STR, VK_INT, VK_TRUE, VK_FALSE, VK_NULL, VK_OBJ)
+
+
+def _flatten_doc(queues: Dict[str, List[Change]], flat: _Flat,
+                 walk=None) -> Tuple[int, int, bool, bool]:
+    """Append one doc to ``flat``, in delivery order (no sort).  ``walk``
+    is the native flatten (``native.flatten_walker()``), or None.  Returns
+    the doc's change and op counts, whether its ops became rows, and
+    whether the native walk made them."""
+    if walk is not None:
+        walked = walk(queues, _WALK_CONSTS, len(flat.attr_strs), len(flat.key_strs))
+        if walked is not None:
+            changes, actors, bounds, *columns = walked
+            flat.add_doc(changes, actors, True, bounds, *columns)
+            return len(changes), sum(bounds), True, True
     changes = [ch for log in queues.values() for ch in log]
     try:
-        return len(changes), _flatten_rows(changes, flat), True
+        return len(changes), _flatten_rows(changes, flat), True, False
     except _UNEXPRESSED:
         # it enters the native call empty, each stream with room for every
         # op (an op makes at most one row), and is split in Python after it
         n_ops = sum(len(ch.ops) for ch in changes)
-        flat.add_doc(changes, [], False, (n_ops,) * 4, array("i"), array("i"))
-        return len(changes), n_ops, False
+        flat.add_doc(changes, [], False, (n_ops,) * 4, b"", b"")
+        return len(changes), n_ops, False, False
 
 
 def _alloc_streams(sizes) -> Dict[str, np.ndarray]:
@@ -590,11 +609,16 @@ def _encode_columnar(workloads, tracer, widths, finish):
     doc's first row per stream, and the rows its streams took (zero where
     encode fell back)."""
     flat = _Flat()
+    walk = native.flatten_walker()
+    walked = 0
     for doc_index, queues in enumerate(workloads):
         with tracer.span("batch.encode.split", doc=doc_index) as sp:
-            sp.args["changes"], sp.args["ops"], sp.args["rows"] = (
-                _flatten_doc(queues, flat))
+            sp.args["changes"], sp.args["ops"], sp.args["rows"], by_walk = (
+                _flatten_doc(queues, flat, walk))
+        walked += by_walk
     d = len(workloads)
+    GLOBAL_COUNTERS.add("encode.flatten.native", walked)
+    GLOBAL_COUNTERS.add("encode.flatten.python", d - walked)
     with tracer.span("batch.encode.pad"):
         bounds = np.asarray(flat.bounds, np.int64).reshape(d, 4)
         if widths is None:

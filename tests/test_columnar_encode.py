@@ -1,16 +1,21 @@
 """The columnar batch encode (one flatten per doc, one native schedule and
 scatter for the batch) against the per-doc Python encode: every array,
-table and fallback doc equal, byte for byte."""
+table and fallback doc equal, byte for byte; and the native flatten
+against the Python one, column for column."""
 
 import random
 
 import pytest
 
+from benchmark.drivers._pool import to_program
+from benchmark.gen.editing_trace import Trace, history
+from benchmark.run import ROOT as BENCH_ROOT
+from benchmark.run import load_json, metric_reader
 from peritext_tpu import native
 from peritext_tpu.core.errors import PeritextError
 from peritext_tpu.core.opids import HEAD, ROOT
-from peritext_tpu.core.types import Change, Operation
-from peritext_tpu.obs import GLOBAL_COUNTERS, Tracer
+from peritext_tpu.core.types import AFTER, BEFORE, Boundary, Change, Operation
+from peritext_tpu.obs import GLOBAL_COUNTERS, Tracer, metrics
 from peritext_tpu.ops import encode
 from peritext_tpu.ops.encode import (
     MAP_STREAM_COLS,
@@ -130,6 +135,81 @@ def _foreign_op_actor():
     return {"doc1": [initial, c]}
 
 
+def _text_change(*ops):
+    """The "ab" text's origin change and one more change of doc1 holding
+    ``ops``, each given as a function of the text's id (op counters from 4)."""
+    _, _, initial = generate_docs("ab", 1)
+    text = initial.ops[0].opid
+    ops = [make(text, (4 + i, "doc1")) for i, make in enumerate(ops)]
+    return {"doc1": [initial, _change("doc1", 2, {"doc1": 1}, ops)]}
+
+
+def _non_bmp():
+    """Inserts of a code point beyond the BMP and one beyond Latin-1."""
+    return _text_change(
+        lambda t, o: Operation("set", t, o, elem_id=HEAD, insert=True, value="\U0001F600"),
+        lambda t, o: Operation("set", t, o, elem_id=(4, "doc1"), insert=True, value="\u0101"))
+
+
+def _mark(mark_type, attrs, end=(3, "doc1")):
+    return lambda t, o: Operation("addMark", t, o, start=Boundary(BEFORE, (2, "doc1")),
+                                  end=Boundary(AFTER, end), mark_type=mark_type,
+                                  attrs=attrs)
+
+
+def _empty_url():
+    """A link whose url is the empty string: a value, not an absent attr."""
+    return _text_change(_mark("link", {"url": ""}), _mark("strong", {}))
+
+
+def _url_and_id():
+    """Mark attrs with both keys: the url is the attr."""
+    return _text_change(_mark("link", {"url": "https://a.example", "id": "c1"}),
+                        _mark("comment", {"id": "c1"}), _mark("comment", {"id": "c1"}))
+
+
+def _unknown_dep_actor():
+    """A dep on an actor that sent no change (at seq 0, so the doc still
+    schedules): the row flatten leaves the doc to encode_doc."""
+    log = _text_change(
+        lambda t, o: Operation("set", t, o, elem_id=HEAD, insert=True, value="x"))
+    log["doc1"][1].deps["ghost"] = 0
+    return log
+
+
+def _counter_at_max():
+    """An op id counter of MAX_CTR exactly: it packs."""
+    _, _, initial = generate_docs("ab", 1)
+    text = initial.ops[0].opid
+    c = _change("doc1", 2, {"doc1": 1}, [
+        Operation("set", text, (MAX_CTR, "doc1"), elem_id=HEAD, insert=True, value="x")])
+    return {"doc1": [initial, c]}
+
+
+def _counter_over_max_in_mark():
+    """A mark boundary on an element whose counter is just over MAX_CTR."""
+    return _text_change(_mark("em", None, end=(MAX_CTR + 1, "doc1")))
+
+
+def _actors(n):
+    """A doc edited by ``n`` actors: doc1 and n - 1 root-map writers."""
+    _, _, initial = generate_docs("ab", 1)
+    log = {"doc1": [initial]}
+    for i in range(n - 1):
+        actor = f"w{i:04d}"
+        log[actor] = [_change(actor, 1, {"doc1": 1}, [
+            Operation("set", ROOT, (10, actor), key="k", value=i)])]
+    return log
+
+
+def _b4(seed):
+    """A B4-shaped one-author history at the book-length cell's rehearse size."""
+    rehearse = load_json(BENCH_ROOT / "benchmark/configs/peritext_longdoc.json")["rehearse"]
+    params = load_json(BENCH_ROOT / "benchmark/traffic/editing_trace_b4.json")["trace"]
+    trace = Trace.of(dict(params, inserts=rehearse["inserts"], deletes=rehearse["deletes"]))
+    return to_program(history(seed, trace))
+
+
 def _duplicated(w):
     """Every doc's first log delivered twice more, out of order."""
     out = []
@@ -161,6 +241,14 @@ CASES = {
     "empty": (lambda: [{}, {"doc1": []}, *generate_workload(seed=2, num_docs=1,
                                                           ops_per_doc=20)], {}),
     "no_docs": (lambda: [], {"mark_capacity": 16}),
+    "non_bmp": (lambda: [_non_bmp(), *generate_workload(seed=13, num_docs=1,
+                                                       ops_per_doc=30)], {}),
+    "mark_attrs": (lambda: [_empty_url(), _url_and_id()], {}),
+    "unknown_dep": (lambda: [_unknown_dep_actor(), *generate_workload(seed=14, num_docs=1,
+                                                                     ops_per_doc=30)], {}),
+    "counters": (lambda: [_counter_at_max(), _counter_over_max_in_mark()], {}),
+    "actors_1023_1024": (lambda: [_actors(MAX_ACTORS), _actors(MAX_ACTORS + 1)], {}),
+    "b4": (lambda: [_b4(5), _b4(2**31 + 9)], {}),
 }
 
 
@@ -173,7 +261,7 @@ def test_columnar_encode_matches_python(case, monkeypatch):
 
 
 @pytest.mark.parametrize("case", ["fuzz_seed3", "markheavy", "map_heavy", "fallbacks",
-                                  "duplicates", "empty"])
+                                  "duplicates", "empty", "non_bmp", "unknown_dep", "b4"])
 def test_columnar_doc_streams_match_python(case, monkeypatch):
     make, _ = CASES[case]
     got = encode_doc_streams(make())
@@ -190,6 +278,90 @@ def test_expected_fallbacks_are_the_python_ones():
     enc = encode_workloads(CASES["fallbacks"][0]())
     assert enc.fallback_docs == [0, 3, 4]  # second list, float value, counter
     assert encode_workloads(CASES["many_actors"][0]()).fallback_docs == [0]
+    assert encode_workloads(CASES["counters"][0]()).fallback_docs == [1]
+    assert encode_workloads(CASES["actors_1023_1024"][0]()).fallback_docs == [1]
+
+
+_FLAT_FIELDS = ("heads", "deps", "ops", "doc_ch_off", "doc_int_off", "doc_n_actors",
+                "expressed", "bounds", "attr_strs", "key_strs", "doc_attr_off",
+                "doc_key_off", "actors")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_native_flatten_matches_python(case):
+    """Doc by doc into one batch (so the string ids start past the docs
+    before): the native walk gives the Python flatten's columns, byte for
+    byte, and declines exactly the docs where the Python flatten raises."""
+    walk = native.flatten_walker()
+    assert walk is not None
+    workloads = CASES[case][0]()
+    flat_native, flat_python = encode._Flat(), encode._Flat()
+    for queues in workloads:
+        *counts, rows, walked = encode._flatten_doc(queues, flat_native, walk)
+        want = encode._flatten_doc(queues, flat_python, None)
+        assert want[3] is False
+        assert [*counts, rows] == list(want[:3])
+        assert walked == rows  # declined exactly where _flatten_rows raises
+    for name in _FLAT_FIELDS:
+        got, want = getattr(flat_native, name), getattr(flat_python, name)
+        if name in ("heads", "deps", "ops"):
+            got, want = got.tobytes(), want.tobytes()
+        assert got == want, name
+    assert [list(map(id, c)) for c in flat_native.changes] == [
+        list(map(id, c)) for c in flat_python.changes]
+
+
+def test_native_flatten_declines_what_it_does_not_read():
+    """Types the walk does not read exactly are left to the Python flatten,
+    which takes them: a bool seq, a list op id, a str subclass value, a
+    tuple of changes for a log."""
+    walk = native.flatten_walker()
+
+    class Char(str):
+        pass
+
+    for edit in (lambda log: setattr(log["doc1"][-1], "seq", True),
+                 lambda log: setattr(log["doc1"][-1].ops[0], "opid",
+                                     list(log["doc1"][-1].ops[0].opid)),
+                 lambda log: setattr(log["doc1"][-1].ops[0], "value", Char("x")),
+                 lambda log: log.update(doc1=tuple(log["doc1"]))):
+        log = _text_change(
+            lambda t, o: Operation("set", t, o, elem_id=HEAD, insert=True, value="x"))
+        edit(log)
+        assert walk(log, encode._WALK_CONSTS, 0, 0) is None
+        flat = encode._Flat()
+        assert encode._flatten_doc(log, flat, walk)[2:] == (True, False)
+
+
+def test_native_flatten_reads_every_op_on_every_encode(monkeypatch):
+    """Nothing of a doc survives an encode: an op changed in place between
+    two encodes changes the second's rows, and each encode walks every doc."""
+    fresh = metrics.Counters()
+    monkeypatch.setattr(encode, "GLOBAL_COUNTERS", fresh)
+    w = generate_workload(seed=31, num_docs=3, ops_per_doc=60)
+    first = encode_workloads(w)
+    assert fresh.get("encode.flatten.native") == 3
+    op = next(op for ch in w[1]["doc1"][1:] for op in ch.ops if op.insert)
+    op.value = "Q" if op.value != "Q" else "R"
+    second = encode_workloads(w)
+    assert fresh.get("encode.flatten.native") == 6
+    assert fresh.get("encode.flatten.python") == 0
+    assert (first.ins_char[1] != second.ins_char[1]).sum() >= 1
+    assert (first.ins_char[[0, 2]] == second.ins_char[[0, 2]]).all()
+    _assert_same_batch(second, _python_encode(monkeypatch, encode_workloads, w))
+
+
+def test_without_the_walker_every_doc_flattens_in_python(monkeypatch):
+    fresh = metrics.Counters()
+    monkeypatch.setattr(metrics, "GLOBAL_COUNTERS", fresh)
+    monkeypatch.setattr(encode, "GLOBAL_COUNTERS", fresh)
+    monkeypatch.setattr(native, "flatten_walker", lambda: None)
+    make, caps = CASES["fallbacks"]
+    got = encode_workloads(make(), **caps)
+    _assert_same_batch(got, _python_encode(monkeypatch, encode_workloads, make(), **caps))
+    assert fresh.get("encode.flatten.python") == got.num_docs
+    assert fresh.get("encode.flatten.native") == 0
+    assert metric_reader("batch.encode.native_flatten_pct")(None) == 0.0
 
 
 @pytest.mark.parametrize("drop", ["first", "middle", "unexpressed"])
