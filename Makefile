@@ -85,9 +85,9 @@ history-smoke:
 bench-serve:
 	$(PY) bench.py --mode serve
 
-# paged-storage smoke (mirrors the CI paged-smoke job): small long-tail
-# session through both layouts — byte equality (spans/patches/digests),
-# occupancy improvement direction, peritext_page_* gauges + /devprof.json
+# paged-storage smoke (mirrors the CI paged-smoke job): a small streaming
+# session through the padded and paged layouts — byte equality
+# (spans/patches/digests), peritext_page_* gauges + /devprof.json
 # page_pool section (artifacts land in /tmp/pt-paged)
 paged-smoke:
 	$(CPU_ENV) $(PY) scripts/paged_smoke.py --out /tmp/pt-paged
@@ -99,8 +99,8 @@ paged-smoke:
 ragged-smoke:
 	$(CPU_ENV) $(PY) scripts/ragged_smoke.py --out /tmp/pt-ragged
 
-# long-tail layout comparison row: one essay among a tweet fleet, all
-# three layouts measured, byte equality asserted, waste ratio reported
+# long-tail layout comparison row: one essay among a tweet fleet, padded
+# and ragged DocBatch measured, byte equality asserted, waste reported
 bench-longdoc:
 	$(PY) bench.py --mode longdoc
 

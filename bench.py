@@ -1582,15 +1582,12 @@ def run_longdoc(args) -> dict:
     because every tweet pays the essay's stream width and slot bucket.
 
     The SAME workload merges through the padded DocBatch (the byte-equality
-    oracle), the paged DocBatch (store/: page pool + per-doc page tables,
-    size-bucketed groups) and the ragged DocBatch (ops/ragged.py: one
-    program over the pool, per-doc counts as data — ISSUE 12); the row
-    asserts byte equality, then reports every layout's wall clock and
-    padded-op waste.  Headline = ragged throughput; ``vs_baseline`` =
-    ragged/paged speedup (the bucket ladder this layout kills);
-    ``vs_padded`` and the waste ratio (absolute padded ops burned,
-    padded / paged; ragged burns ZERO) ride along.  ``--docs`` sizes the
-    tweet fleet, ``--ops-per-doc`` the essay."""
+    oracle) and the ragged DocBatch (store/'s page pool, one program over
+    it, per-doc counts as data); the row asserts byte equality, then
+    reports both layouts' wall clock and padded-op waste.  Headline =
+    ragged throughput; ``vs_baseline`` = ragged/padded speedup (ragged
+    burns ZERO padded ops).  ``--docs`` sizes the tweet fleet,
+    ``--ops-per-doc`` the essay."""
     import jax
 
     from peritext_tpu.api.batch import DocBatch
@@ -1609,9 +1606,9 @@ def run_longdoc(args) -> dict:
 
     # slot capacity: power of two covering the essay (both layouts share
     # it — the padded layout must pay it for EVERY doc, which is the row's
-    # whole point; paged pays it only in the essay's page table).  Rounded
+    # whole point; ragged pays it only in the essay's page table).  Rounded
     # to a page multiple so an odd --slots can't pass the padded half and
-    # then crash the paged half's alignment check.
+    # then crash the ragged half's alignment check.
     from peritext_tpu.store import DEFAULT_PAGE_SIZE
 
     slots = args.slots or 256
@@ -1632,13 +1629,11 @@ def run_longdoc(args) -> dict:
             t_best = dt if t_best is None or dt < t_best else t_best
         return batch, report, t_best
 
-    padded_batch, padded, wall_padded = measure("padded")
-    paged_batch, paged, wall_paged = measure("paged")
+    _, padded, wall_padded = measure("padded")
     ragged_batch, ragged, wall_ragged = measure("ragged")
-    for name, rep in (("paged", paged), ("ragged", ragged)):
-        assert padded.spans == rep.spans, f"{name} layout diverged from padded"
-        assert padded.roots == rep.roots, f"{name} roots diverged from padded"
-        assert padded.fallback_docs == rep.fallback_docs
+    assert padded.spans == ragged.spans, "ragged layout diverged from padded"
+    assert padded.roots == ragged.roots, "ragged roots diverged from padded"
+    assert padded.fallback_docs == ragged.fallback_docs
 
     # padded-op waste: absolute padded stream ops burned per layout (the
     # devprof occupancy quantity, derivable here from padding_efficiency)
@@ -1649,19 +1644,15 @@ def run_longdoc(args) -> dict:
         return capacity - real, capacity
 
     waste_padded, cap_padded = wasted(padded)
-    waste_paged, cap_paged = wasted(paged)
     waste_ragged, cap_ragged = wasted(ragged)
-    pool_paged = paged_batch.last_store.pool_stats()
     pool = ragged_batch.last_store.pool_stats()
     value = total_ops / wall_ragged
     return {
         "metric": "longdoc_ragged_ops_per_sec",
         "value": round(value, 1),
         "unit": "ops/s",
-        "vs_baseline": round(wall_paged / wall_ragged, 2),
-        "baseline_impl": "same long-tail workload through the paged "
-                         "(pow-2 bucketed) layout",
-        "vs_padded": round(wall_padded / wall_ragged, 2),
+        "vs_baseline": round(wall_padded / wall_ragged, 2),
+        "baseline_impl": "same long-tail workload through the padded layout",
         "docs": d_small + 1,
         "small_doc_ops": small_ops,
         "big_doc_ops": big_ops,
@@ -1669,19 +1660,13 @@ def run_longdoc(args) -> dict:
         "slot_capacity": slots,
         "byte_equal": True,
         "padded_ops_per_sec": round(total_ops / wall_padded, 1),
-        "paged_ops_per_sec": round(total_ops / wall_paged, 1),
         "wall_padded_s": round(wall_padded, 3),
-        "wall_paged_s": round(wall_paged, 3),
         "wall_ragged_s": round(wall_ragged, 3),
         "stream_capacity_padded": round(cap_padded),
-        "stream_capacity_paged": round(cap_paged),
         "stream_capacity_ragged": round(cap_ragged),
         "padded_ops_wasted": round(waste_padded),
-        "paged_ops_wasted": round(waste_paged),
         "ragged_ops_wasted": round(waste_ragged),
-        "waste_ratio": round(waste_padded / waste_paged, 2) if waste_paged else None,
         "state_slots_padded": (d_small + 1) * slots,
-        "state_slots_paged": pool_paged["pages_in_use"] * pool_paged["page_size"],
         "state_slots_ragged": pool["pages_in_use"] * pool["page_size"],
         "page_pool": pool,
         "workload_gen_seconds": round(gen_time, 1),
@@ -2216,7 +2201,7 @@ def main() -> None:
              "shard counts (single-device byte equality in-row, ISSUE 14); "
              "storm = reconnect-storm "
              "backlog drain under serving load; longdoc = long-tail "
-             "paged-vs-padded comparison (one essay among a tweet fleet, "
+             "ragged-vs-padded comparison (one essay among a tweet fleet, "
              "ISSUE 8); markheavy = mark-heavy editorial pass (span-overlap "
              "explosion, scalar-oracle byte equality in-row, ISSUE 10); "
              "fleet-serve = host-kill failover episode as a measurement "
@@ -2242,7 +2227,7 @@ def main() -> None:
         "--layout", choices=("padded", "paged", "ragged"), default="padded",
         help="resident-state storage layout for the sweep row and (ragged "
              "only) the batch row's one-program-over-the-pool variant; the "
-             "longdoc row always measures all three layouts itself",
+             "longdoc row always measures both of its layouts itself",
     )
     parser.add_argument(
         "--profile", default=None, metavar="DIR",

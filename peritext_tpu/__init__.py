@@ -15,8 +15,8 @@ rich-text CRDT; reference mounted at /root/reference) re-designed for TPU:
   backend behind the InputOperation/Patch boundary), and the editor bridge.
 * :mod:`peritext_tpu.store` — paged document storage: a device-resident
   global pool of fixed-size op pages + per-doc page tables behind
-  ``layout="paged"`` on DocBatch/StreamingMerge (the padded layout stays
-  the byte-equality oracle).
+  ``layout="ragged"`` on DocBatch and ``layout="paged"``/``"ragged"`` on
+  StreamingMerge (the padded layout stays the byte-equality oracle).
 * :mod:`peritext_tpu.testing` — fuzz harness, trace replay, patch-accumulation
   oracle.
 """

@@ -275,8 +275,8 @@ def _round8(n: int) -> int:
 
 
 #: shared all-empty stream set: the stand-in for capacity-fallback docs in
-#: grouped (paged) encoding — their real streams must not inflate a group's
-#: widths, and their rows stay all-zero no-ops
+#: the ragged batch encode — their real streams must not inflate the
+#: batch's widths, and their rows stay all-zero no-ops
 _EMPTY_STREAMS = _DocStreams()
 
 #: the device columns of each stream, in stream order: insert, delete,
@@ -709,10 +709,10 @@ def encode_doc_streams(
     its streams read back as rows under one ``batch.encode.rows`` span;
     without it, the per-doc Python loop.
 
-    Exposed separately so the paged layout (api/batch.py ``layout="paged"``)
-    can group docs by size BEFORE padding — each size bucket pads to its own
-    widths via :func:`pad_doc_streams` instead of every doc paying the
-    widest doc's stream width."""
+    Exposed separately so the ragged layout (api/batch.py
+    ``layout="ragged"``) can apply its per-doc capacity thresholds BEFORE
+    padding, then pad the batch to its own true maxima via
+    :func:`pad_doc_streams`."""
     tracer = tracer if tracer is not None else GLOBAL_TRACER
     if not native_loaded():
         return _encode_doc_streams_python(workloads, tracer)

@@ -100,7 +100,7 @@ def sink():
 
 
 needs_native = pytest.mark.skipif(not native.available(), reason="needs the native core")
-LAYOUTS = ["padded", "paged", "ragged"]
+LAYOUTS = ["padded", "ragged"]
 #: the encode path as a test parameter: the native one under the plain id,
 #: the per-doc Python one (a failed native build) under "<id>-python"
 PATHS = [pytest.param("native", id="native", marks=needs_native),
@@ -109,16 +109,15 @@ PATHS = [pytest.param("native", id="native", marks=needs_native),
 #: the encode's stage spans (names, doc args) for 3 docs, by path and
 #: layout.  With the native core: a flatten (split) per doc, the allocation
 #: (pad), one schedule and scatter (sort) and the tables (pad), and the
-#: paged and ragged layouts read each doc's rows back (rows) and pad their
-#: groups after that.  In Python: a sort then a split per doc, then one pad.
+#: ragged layout reads each doc's rows back (rows) and pads the batch after
+#: that.  In Python: a sort then a split per doc, then one pad.
 _NATIVE_PADDED = ([STAGES[1]] * 3 + [STAGES[2], STAGES[0], STAGES[2]],
                   [0, 1, 2, None, None, None])
-_NATIVE_GROUPED = (_NATIVE_PADDED[0] + ["batch.encode.rows", STAGES[2]],
-                   _NATIVE_PADDED[1] + [None, None])
+_NATIVE_RAGGED = (_NATIVE_PADDED[0] + ["batch.encode.rows", STAGES[2]],
+                  _NATIVE_PADDED[1] + [None, None])
 _PYTHON = (STAGES[:2] * 3 + STAGES[2:], [0, 0, 1, 1, 2, 2, None])
 STAGE_SPANS = {
-    "native": {"padded": _NATIVE_PADDED, "paged": _NATIVE_GROUPED,
-               "ragged": _NATIVE_GROUPED},
+    "native": {"padded": _NATIVE_PADDED, "ragged": _NATIVE_RAGGED},
     "python": dict.fromkeys(LAYOUTS, _PYTHON),
 }
 

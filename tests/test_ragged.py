@@ -209,6 +209,164 @@ def test_docbatch_ragged_validation():
         DocBatch(layout="ragged", mesh=mesh_like)
 
 
+@pytest.mark.parametrize("layout", ["paged", "nonsense"])
+def test_docbatch_rejects_unknown_layout(layout):
+    # "paged" is a StreamingMerge layout, not a DocBatch one
+    with pytest.raises(ValueError):
+        DocBatch(layout=layout)
+
+
+def _batch_parity(wl, cursors=None, **kw):
+    """Merge ``wl`` under both layouts, assert every result equal, and
+    return the (padded, ragged) reports."""
+    rp = DocBatch(layout="padded", **kw).merge(wl, cursors=cursors)
+    rr = DocBatch(layout="ragged", **kw).merge(wl, cursors=cursors)
+    assert rr.spans == rp.spans
+    assert rr.roots == rp.roots
+    assert rr.fallback_docs == rp.fallback_docs
+    assert rr.device_ops == rp.device_ops
+    assert rr.cursor_positions == rp.cursor_positions
+    return rp, rr
+
+
+@pytest.mark.parametrize("seed", [3, 11, 42])
+def test_docbatch_ragged_matches_padded_on_fuzz_seeds(seed):
+    wl = generate_workload(seed=seed, num_docs=8, ops_per_doc=60)
+    _batch_parity(wl, cursors=[[] for _ in wl],
+                  slot_capacity=256, mark_capacity=64)
+
+
+#: capacities that route docs of seed 7's workload to the oracle: the
+#: configured caps act as fallback thresholds identically under both
+#: layouts, and a slot overflow falls back after the device apply
+CAPACITY_FALLBACKS = {
+    "marks": dict(slot_capacity=256, mark_capacity=8, page_size=64),
+    "slots": dict(slot_capacity=32, mark_capacity=64, page_size=32),
+    "ops": dict(slot_capacity=256, mark_capacity=64, op_capacity=32,
+                page_size=64),
+}
+
+
+@pytest.mark.parametrize("caps", sorted(CAPACITY_FALLBACKS))
+def test_docbatch_ragged_matches_padded_under_capacity_fallbacks(caps):
+    wl = generate_workload(seed=7, num_docs=6, ops_per_doc=70)
+    rp, _ = _batch_parity(wl, **CAPACITY_FALLBACKS[caps])
+    assert rp.fallback_docs
+
+
+def test_docbatch_ragged_cursor_parity():
+    from peritext_tpu.api.batch import _oracle_doc
+
+    wl = generate_workload(seed=2, num_docs=4, ops_per_doc=50)
+    curs = []
+    for w in wl:
+        doc = _oracle_doc(w)
+        lids = [oid for oid, m in doc._metadata.items() if isinstance(m, list)]
+        row = []
+        if lids and doc._metadata[lids[0]]:
+            el = doc._metadata[lids[0]][0].elem_id
+            row = [{"objectId": lids[0], "elemId": el}]
+        curs.append(row)
+    _batch_parity(wl, cursors=curs)
+
+
+def test_docbatch_ragged_matches_padded_on_recorded_traces():
+    from peritext_tpu.testing.traces import available_traces, load_trace_queues
+
+    traces = available_traces()
+    if not traces:
+        pytest.skip("no recorded reference traces in this image")
+    wl = [load_trace_queues(t) for t in traces[:4]]
+    _batch_parity(wl, slot_capacity=1024, mark_capacity=256)
+
+
+def test_docbatch_ragged_occupancy_beats_padded_on_longtail():
+    """One essay among tweets: padded pays the essay's stream widths for
+    every tweet; ragged dispatches the real ops alone."""
+    wl = generate_workload(seed=5, num_docs=12, ops_per_doc=8)
+    wl += generate_workload(seed=501, num_docs=1, ops_per_doc=300)
+    rp, rr = _batch_parity(wl, slot_capacity=512, mark_capacity=128)
+    assert rr.stats.padding_efficiency == 1.0 > rp.stats.padding_efficiency
+
+    def wasted(r):
+        real = r.stats.device_ops + r.stats.fallback_ops
+        eff = r.stats.padding_efficiency
+        return real / eff - real if eff else 0.0
+
+    assert wasted(rr) == 0.0 < wasted(rp)
+
+
+# ---------------------------------------------------------------------------
+# DocBatch.merge's shared tail, under both layouts
+# ---------------------------------------------------------------------------
+
+LAYOUTS = ("padded", "ragged")
+#: the per-layout step that places docs on the device and applies them
+APPLY_STEP = {"padded": "apply_encoded", "ragged": "_apply_ragged"}
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_docbatch_guarded_merge_degrades_to_oracle(layout, monkeypatch):
+    from peritext_tpu.api.batch import _oracle_doc, oracle_merge
+    from peritext_tpu.obs import GLOBAL_COUNTERS
+
+    wl = generate_workload(seed=41, num_docs=3, ops_per_doc=20)
+
+    def boom(enc):
+        raise RuntimeError("injected device failure")
+
+    batch = DocBatch(layout=layout, guard=True)
+    monkeypatch.setattr(batch, APPLY_STEP[layout], boom)
+    before = GLOBAL_COUNTERS.get("merge.guarded_fallbacks")
+    report = batch.merge(wl)
+    assert GLOBAL_COUNTERS.get("merge.guarded_fallbacks") == before + 1
+    assert report.spans == oracle_merge(wl)
+    assert report.roots == [_oracle_doc(w).root for w in wl]
+    assert report.fallback_docs == [0, 1, 2]
+    assert report.device_ops == 0
+    assert report.stats.device_docs == 0
+    assert report.stats.extras["guarded_fallback"] == 1.0
+    assert "injected device failure" in report.stats.extras["guarded_error"]
+    # unguarded, the device failure propagates
+    strict = DocBatch(layout=layout)
+    monkeypatch.setattr(strict, APPLY_STEP[layout], boom)
+    with pytest.raises(RuntimeError):
+        strict.merge(wl)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_docbatch_accounting_agrees_across_layouts(layout):
+    from peritext_tpu.obs import GLOBAL_COUNTERS
+
+    wl = generate_workload(seed=7, num_docs=6, ops_per_doc=70)
+    kw = CAPACITY_FALLBACKS["ops"]
+    other = "ragged" if layout == "padded" else "padded"
+    ref = DocBatch(layout=other, **kw).merge(wl)
+    names = ("merge.calls", "merge.ragged_calls", "merge.device_ops",
+             "merge.fallback_docs")
+    before = {n: GLOBAL_COUNTERS.get(n) for n in names}
+    report = DocBatch(layout=layout, **kw).merge(wl)
+    moved = {n: GLOBAL_COUNTERS.get(n) - before[n] for n in names}
+
+    s = report.stats
+    assert report.fallback_docs  # the op cap sends a doc to the oracle
+    assert s.fallback_docs == len(report.fallback_docs)
+    assert s.device_docs == len(wl) - s.fallback_docs
+    assert s.device_ops == report.device_ops == sum(
+        len(ch.ops) for d, w in enumerate(wl) if d not in report.fallback_docs
+        for log in w.values() for ch in log
+    )
+    fields = ("device_ops", "fallback_ops", "fallback_docs", "device_docs")
+    assert ({f: getattr(s, f) for f in fields}
+            == {f: getattr(ref.stats, f) for f in fields})
+    assert moved == {
+        "merge.calls": 1,
+        "merge.ragged_calls": int(layout == "ragged"),
+        "merge.device_ops": report.device_ops,
+        "merge.fallback_docs": len(report.fallback_docs),
+    }
+
+
 # ---------------------------------------------------------------------------
 # streaming: RaggedStreamingMerge vs the padded session
 # ---------------------------------------------------------------------------

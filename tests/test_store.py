@@ -1,8 +1,8 @@
 """Paged document storage (peritext_tpu/store/): the byte-equality oracle
 and the subsystem invariants.
 
-The paged layout's correctness contract is blunt: for every fuzz seed and
-recorded trace, the paged backend must produce IDENTICAL final docs,
+The paged layout's correctness contract is blunt: for every fuzz seed,
+the paged streaming backend must produce IDENTICAL final docs,
 patches and store digests to the padded backend — the padded path stays
 resident as the oracle.  On top of that: allocator determinism (page
 tables are replicated state), typed pool exhaustion, checkpoint round-trip
@@ -16,7 +16,6 @@ import tempfile
 import numpy as np
 import pytest
 
-from peritext_tpu.api.batch import DocBatch, _oracle_doc
 from peritext_tpu.parallel.codec import encode_frame
 from peritext_tpu.parallel.streaming import StreamingMerge
 from peritext_tpu.store import PageAllocator, PagedDocStore, PoolExhausted
@@ -112,98 +111,6 @@ def test_store_default_tomb_capacity_matches_padded_layout():
 def test_store_rejects_unaligned_slot_capacity():
     with pytest.raises(ValueError):
         PagedDocStore(2, slot_capacity=100, mark_capacity=8, page_size=64)
-
-
-# ---------------------------------------------------------------------------
-# DocBatch: paged vs padded byte equality (the oracle)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("seed", [3, 11, 42])
-def test_batch_paged_matches_padded_on_fuzz_seeds(seed):
-    wl = generate_workload(seed=seed, num_docs=8, ops_per_doc=60)
-    curs = [[] for _ in wl]
-    p = DocBatch(slot_capacity=256, mark_capacity=64).merge(wl, cursors=curs)
-    q = DocBatch(slot_capacity=256, mark_capacity=64,
-                 layout="paged").merge(wl, cursors=curs)
-    assert p.spans == q.spans
-    assert p.roots == q.roots
-    assert p.fallback_docs == q.fallback_docs
-    assert p.device_ops == q.device_ops
-    assert p.cursor_positions == q.cursor_positions
-
-
-def test_batch_paged_matches_padded_under_capacity_fallbacks():
-    """The configured capacities act as fallback thresholds identically
-    under both layouts — tiny caps route the same docs to the oracle."""
-    wl = generate_workload(seed=7, num_docs=6, ops_per_doc=70)
-    for kw in (
-        dict(slot_capacity=256, mark_capacity=8),   # mark-capacity fallback
-        dict(slot_capacity=64, mark_capacity=64),   # slot overflow
-        dict(slot_capacity=256, mark_capacity=64, op_capacity=32),
-    ):
-        p = DocBatch(**kw).merge(wl)
-        q = DocBatch(layout="paged", page_size=64, **kw).merge(wl)
-        assert p.spans == q.spans, kw
-        assert p.fallback_docs == q.fallback_docs, kw
-        assert p.roots == q.roots, kw
-
-
-def test_batch_paged_cursor_parity():
-    wl = generate_workload(seed=2, num_docs=4, ops_per_doc=50)
-    curs = []
-    for w in wl:
-        doc = _oracle_doc(w)
-        lids = [oid for oid, m in doc._metadata.items() if isinstance(m, list)]
-        row = []
-        if lids and doc._metadata[lids[0]]:
-            el = doc._metadata[lids[0]][0].elem_id
-            row = [{"objectId": lids[0], "elemId": el}]
-        curs.append(row)
-    p = DocBatch().merge(wl, cursors=curs)
-    q = DocBatch(layout="paged").merge(wl, cursors=curs)
-    assert p.cursor_positions == q.cursor_positions
-
-
-def test_batch_paged_matches_padded_on_recorded_traces():
-    from peritext_tpu.testing.traces import available_traces, load_trace_queues
-
-    traces = available_traces()
-    if not traces:
-        pytest.skip("no recorded reference traces in this image")
-    wl = [load_trace_queues(t) for t in traces[:4]]
-    p = DocBatch(slot_capacity=1024, mark_capacity=256).merge(wl)
-    q = DocBatch(slot_capacity=1024, mark_capacity=256,
-                 layout="paged").merge(wl)
-    assert p.spans == q.spans
-    assert p.fallback_docs == q.fallback_docs
-
-
-def test_batch_paged_occupancy_beats_padded_on_longtail():
-    """One essay among tweets: the paged layout must burn strictly less
-    padded stream capacity (the acceptance direction bench longdoc gates
-    at >= 5x on the full row; the unit test pins the direction)."""
-    wl = generate_workload(seed=5, num_docs=12, ops_per_doc=8)
-    wl += generate_workload(seed=501, num_docs=1, ops_per_doc=300)
-    p = DocBatch(slot_capacity=512, mark_capacity=128).merge(wl)
-    q = DocBatch(slot_capacity=512, mark_capacity=128,
-                 layout="paged").merge(wl)
-    assert p.spans == q.spans
-    assert q.stats.padding_efficiency > p.stats.padding_efficiency
-
-    def wasted(r):
-        real = r.stats.device_ops + r.stats.fallback_ops
-        eff = r.stats.padding_efficiency
-        return real / eff - real if eff else 0.0
-
-    assert wasted(p) >= 5.0 * wasted(q)
-
-
-def test_batch_paged_rejects_mesh_and_bad_page_size():
-    with pytest.raises(ValueError):
-        DocBatch(layout="paged", slot_capacity=100)
-    with pytest.raises(ValueError):
-        DocBatch(layout="nonsense")
 
 
 # ---------------------------------------------------------------------------
