@@ -400,7 +400,14 @@ class DocBatch:
 
         d_total = enc.num_docs
         with self.tracer.span("batch.apply.plan"):
-            ins_counts = (np.asarray(enc.ins_op) != 0).sum(axis=1)
+            ins_op = np.asarray(enc.ins_op)
+            ins_counts = (ins_op != 0).sum(axis=1)
+            # inserts that continue a typing run: their reference is the
+            # op just before them in the doc's stream (the insert kernel
+            # splices such a run once, at its end)
+            run_inserts = int(np.count_nonzero(
+                (ins_op[:, 1:] != 0) & (ins_op[:, :-1] != 0)
+                & (np.asarray(enc.ins_ref)[:, 1:] == ins_op[:, :-1])))
             del_counts = (np.asarray(enc.del_target) != 0).sum(axis=1)
             max_pages = max(1, self.slot_capacity // self.page_size)
             page_need = np.minimum(
@@ -428,6 +435,8 @@ class DocBatch:
             "merge.ragged_loop_steps",
             int(ins_counts.max(initial=0)) + int(del_counts.max(initial=0)),
         )
+        GLOBAL_COUNTERS.add("merge.ragged_inserts", int(ins_counts.sum()))
+        GLOBAL_COUNTERS.add("merge.ragged_run_inserts", run_inserts)
         store.pool_elem, store.pool_char, store.aux = apply_batch_ragged_jit(
             store.pool_elem, store.pool_char, store.aux,
             *planes, streams, ins_dev,

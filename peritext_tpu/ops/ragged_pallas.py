@@ -26,6 +26,17 @@ streams ride SMEM a ``STREAM_CHUNK`` at a time, one chunk per grid step
 along a second grid axis, with the window and the doc's count carried
 across them: a whole book-length stream does not fit SMEM.
 
+The splice is paid once per typing run, not once per char.  The kernel
+holds a pending run: its window position, its length ``k`` and its
+entries, one lane each in a small VMEM scratch.  An insert whose
+reference is the run's last entry lands right after it (the position
+after the run's first entry holds a smaller id, or is the end, and a
+reference's id is below its op's), so it only appends a lane.  Any other
+insert first flushes the run, shifting the tail right by ``k`` lanes in
+one pass, then looks up its reference and opens a new run.  The doc's
+count includes the pending entries, so the overflow point is unchanged;
+a run is flushed at the end of every stream chunk.
+
 ``input_output_aliases`` pins the pool in place (indices count flattened
 leaves INCLUDING the scalar-prefetch operands — the megablox convention).
 Unowned pool pages are untouched by construction: no page table points at
@@ -105,6 +116,8 @@ def _ragged_insert_kernel(
     # scratch
     elem_scr,        # VMEM (R, P), R = Gmax rounded up to _ROWS
     char_scr,        # VMEM (R, P)
+    pend_op_scr,     # VMEM (_ROWS, P): the pending run's op ids, lane j
+    pend_char_scr,   # VMEM (_ROWS, P): its chars (every row alike)
     carry_scr,       # SMEM (3,): n, ov, hint between stream chunks
     dma_sem,
     *,
@@ -116,6 +129,7 @@ def _ragged_insert_kernel(
     rows, p = elem_scr.shape
     chunk = ins_op_ref.shape[1]
     bsz = _ROWS * p
+    run_cap = min(LANES, p)  # a flush shifts the tail by at most one row
 
     def _copy_pages(pairs, to_window):
         """DMA each of the doc's true pages between the pool and its
@@ -151,85 +165,128 @@ def _ragged_insert_kernel(
     def load(scr, b):
         return scr[pl.ds(pl.multiple_of(b * _ROWS, _ROWS), _ROWS), :]
 
-    def _body(k, carry):
-        n, ov, hint = carry
-        ref = ins_ref_ref[0, k]
-        op = ins_op_ref[0, k]
-        ch = ins_char_ref[0, k]
+    def flush(n, hint, q0, k):
+        """Write the pending run into the window: positions q0.. shift right
+        by k, and q0 .. q0+k-1 take the run's entries.  ``n`` counts the
+        run, so blocks q0 // bsz .. (n-1) // bsz change; they are rewritten
+        from the last down, so each reads its predecessor's last k lanes
+        before that block is rewritten.  Returns the hint: the run's last
+        entry."""
+
+        @pl.when(k > 0)
+        def _():
+            lo = q0 // bsz
+            hi = (n - 1) // bsz + 1
+            kr = lax.rem(k, p)  # a run of a whole row rolls its lanes by 0
+            runs = [(scr, pltpu.roll(pend[...], lax.rem(q0, p), 1))
+                    for scr, pend in ((elem_scr, pend_op_scr),
+                                      (char_scr, pend_char_scr))]
+
+            def block(t, _):
+                b = hi - 1 - t
+                pos = b * bsz + offset
+                for scr, run in runs:
+                    cur = load(scr, b)
+                    prev = jnp.where(b > 0, load(scr, jnp.maximum(b - 1, 0)), 0)
+                    rolled = pltpu.roll(cur, kr, 1)
+                    # lanes < k take the row above's last k lanes; row 0
+                    # those of the previous block's last row
+                    carry_in = jnp.where(
+                        row == 0,
+                        jnp.roll(pltpu.roll(prev, kr, 1), 1, axis=0),
+                        jnp.roll(rolled, 1, axis=0))
+                    shifted = jnp.where(lane < k, carry_in, rolled)
+                    scr[pl.ds(pl.multiple_of(b * _ROWS, _ROWS), _ROWS), :] = jnp.where(
+                        pos < q0, cur, jnp.where(pos < q0 + k, run, shifted))
+                return 0
+
+            lax.fori_loop(0, hi - lo, block, 0)
+
+        return jnp.where(k > 0, q0 + k - 1, hint)
+
+    def _body(t, carry):
+        n, ov, hint, q0, k, last = carry
+        ref = ins_ref_ref[0, t]
+        op = ins_op_ref[0, t]
+        ch = ins_char_ref[0, t]
         live = op != 0
-        is_head = ref == 0
-        used = (n + bsz - 1) // bsz  # blocks holding positions < n
 
-        def match(b):
-            pos = b * bsz + offset
-            return jnp.where((load(elem_scr, b) == ref) & (pos < n), pos, _INF)
+        def extend():
+            # a typing run's next char: its reference is the run's last
+            # entry, and the first position right of that holds a smaller
+            # id than the run's first op (the skip stopped there) or is the
+            # end, so the op lands right after its reference.  The id
+            # compare keeps that exact on any input: a reference's id is
+            # below its op's (counter-major ids, ops/packed.py).
+            for pend, val in ((pend_op_scr, op), (pend_char_scr, ch)):
+                pend[...] = jnp.where(lane == k, val, pend[...])
+            return n + 1, ov, hint, q0, k + 1, op
 
-        # the reference is most often the element the previous step put in
-        # (a typing run): look in that block first, then in every used one
-        # (ids are unique, so the masked min IS the padded argmax)
-        near = jnp.min(match(jnp.minimum(hint // bsz, jnp.maximum(used - 1, 0))))
+        def place():
+            hint_f = flush(n, hint, q0, k)
+            is_head = ref == 0
+            used = (n + bsz - 1) // bsz  # blocks holding positions < n
 
-        def scan():
-            acc = lax.fori_loop(
-                0, used, lambda b, a: jnp.minimum(a, match(b)),
-                jnp.full((_ROWS, p), _INF, jnp.int32),
+            def match(b):
+                pos = b * bsz + offset
+                return jnp.where((load(elem_scr, b) == ref) & (pos < n), pos, _INF)
+
+            # the reference is most often near the previous insert: look in
+            # that block first, then in every used one (ids are unique, so
+            # the masked min IS the padded argmax)
+            near = jnp.min(match(jnp.minimum(hint_f // bsz, jnp.maximum(used - 1, 0))))
+
+            def scan():
+                acc = lax.fori_loop(
+                    0, used, lambda b, a: jnp.minimum(a, match(b)),
+                    jnp.full((_ROWS, p), _INF, jnp.int32),
+                )
+                return jnp.min(acc)
+
+            pmin = lax.cond(is_head | (near < _INF), lambda: near, scan)
+            found = is_head | (pmin < _INF)
+            pref = jnp.where(is_head, jnp.int32(-1), pmin)
+            ok = live & found & (n < cap)
+
+            # convergence skip: first position right of the reference whose
+            # element id is NOT greater than the inserting op's id, else n
+            def skip_more(st):
+                b, q = st
+                return (q == _INF) & (b < used)
+
+            def skip_block(st):
+                b, _ = st
+                pos = b * bsz + offset
+                cand = (pos > pref) & (pos < n) & (load(elem_scr, b) < op)
+                return b + 1, jnp.min(jnp.where(cand, pos, _INF))
+
+            first = jnp.where(ok, (pref + 1) // bsz, used)
+            _, qmin = lax.while_loop(skip_more, skip_block, (first, jnp.int32(_INF)))
+            q = jnp.minimum(qmin, n)
+
+            # the op opens a new run at q; its splice waits for the run's end
+            pend_op_scr[...] = jnp.full((_ROWS, p), op, jnp.int32)
+            pend_char_scr[...] = jnp.full((_ROWS, p), ch, jnp.int32)
+            return (
+                jnp.where(ok, n + 1, n),
+                ov | ((live & ~found) | (live & (n >= cap))).astype(jnp.int32),
+                jnp.where(ok, q, hint_f),
+                q,
+                ok.astype(jnp.int32),
+                op,
             )
-            return jnp.min(acc)
 
-        pmin = lax.cond(is_head | (near < _INF), lambda: near, scan)
-        found = is_head | (pmin < _INF)
-        pref = jnp.where(is_head, jnp.int32(-1), pmin)
-        ok = live & found & (n < cap)
-
-        # convergence skip: first position right of the reference whose
-        # element id is NOT greater than the inserting op's id, else n
-        def skip_more(st):
-            b, q = st
-            return (q == _INF) & (b < used)
-
-        def skip_block(st):
-            b, _ = st
-            pos = b * bsz + offset
-            cand = (pos > pref) & (pos < n) & (load(elem_scr, b) < op)
-            return b + 1, jnp.min(jnp.where(cand, pos, _INF))
-
-        first = jnp.where(ok, (pref + 1) // bsz, used)
-        _, qmin = lax.while_loop(skip_more, skip_block, (first, jnp.int32(_INF)))
-        q = jnp.minimum(qmin, n)
-
-        # the splice: positions q..n shift right by one, q takes the new
-        # element.  Only blocks q // bsz .. n // bsz change; they are
-        # rewritten from the last down, so each reads its predecessor's
-        # last lane before that block is rewritten.
-        lo = q // bsz
-        hi = jnp.where(ok, n // bsz + 1, lo)
-
-        def splice(t, _):
-            b = hi - 1 - t
-            pos = b * bsz + offset
-            for scr, val in ((elem_scr, op), (char_scr, ch)):
-                cur = load(scr, b)
-                prev = jnp.where(b > 0, load(scr, jnp.maximum(b - 1, 0)), 0)
-                rolled = jnp.roll(cur, 1, axis=1)
-                # lane 0 takes the last lane of the row above; row 0 that
-                # of the previous block's last row
-                lane0 = jnp.where(row == 0, jnp.roll(jnp.roll(prev, 1, axis=1), 1, axis=0),
-                                  jnp.roll(rolled, 1, axis=0))
-                shifted = jnp.where(lane == 0, lane0, rolled)
-                scr[pl.ds(pl.multiple_of(b * _ROWS, _ROWS), _ROWS), :] = jnp.where(
-                    pos < q, cur, jnp.where(pos == q, val, shifted))
-            return 0
-
-        lax.fori_loop(0, hi - lo, splice, 0)
-        return (
-            jnp.where(ok, n + 1, n),
-            ov | ((live & ~found) | (live & (n >= cap))).astype(jnp.int32),
-            jnp.where(ok, q, hint),
-        )
+        fast = live & (k > 0) & (ref == last) & (op > last) & (n < cap) & (k < run_cap)
+        return lax.cond(fast, extend, place)
 
     todo = jnp.clip(ins_count_ref[i] - c * chunk, 0, chunk)
-    n1, ov1, hint1 = lax.fori_loop(
-        0, todo, _body, (carry_scr[0], carry_scr[1], carry_scr[2]))
+    zero = jnp.int32(0)
+    n1, ov1, hint1, q0, k, _ = lax.fori_loop(
+        0, todo, _body,
+        (carry_scr[0], carry_scr[1], carry_scr[2], zero, zero, zero))
+    # a run never outlives its chunk: the window holds every entry between
+    # grid steps, and before the scatter
+    hint1 = flush(n1, hint1, q0, k)
     carry_scr[0] = n1
     carry_scr[1] = ov1
     carry_scr[2] = hint1
@@ -289,6 +346,8 @@ def ragged_insert_pallas(
         scratch_shapes=[
             pltpu.VMEM((rows, p), jnp.int32),
             pltpu.VMEM((rows, p), jnp.int32),
+            pltpu.VMEM((_ROWS, p), jnp.int32),
+            pltpu.VMEM((_ROWS, p), jnp.int32),
             pltpu.SMEM((3,), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ],

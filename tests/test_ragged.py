@@ -46,7 +46,8 @@ IMPLS = ("lax", "pallas_interpret")
 
 
 def _ragged_vs_padded(workloads, slot_capacity, mark_capacity, page_size, impl):
-    """Apply one batch both ways; assert every PackedDocs field byte-equal."""
+    """Apply one batch both ways; assert every PackedDocs field byte-equal,
+    and return the ragged side's store."""
     per_doc, fallback, actor_tables, attr_tables, map_tables = (
         encode_doc_streams(workloads)
     )
@@ -83,6 +84,7 @@ def _ragged_vs_padded(workloads, slot_capacity, mark_capacity, page_size, impl):
     # the null page is never owned, so no dispatch may dirty it
     assert np.all(np.asarray(store.pool_elem[0]) == 0)
     assert np.all(np.asarray(store.pool_char[0]) == 0)
+    return store
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -136,6 +138,129 @@ def test_ragged_kernel_streams_in_chunks(monkeypatch):
                           64, 64, 32, "pallas_interpret")
     finally:
         jax.clear_caches()
+
+
+# ---------------------------------------------------------------------------
+# the kernel's pending run: a typing run is spliced once, at its end
+# ---------------------------------------------------------------------------
+
+
+def _one_author(refs, seed=0):
+    """One author's history of single-insert changes: insert ``j`` follows
+    insert ``refs[j]`` (-1: the list head)."""
+    from benchmark.drivers._pool import to_program
+    from benchmark.reference.opids import HEAD, ROOT
+    from benchmark.reference.types import Change, Operation
+
+    rng = random.Random(seed)
+    text = (1, "doc1")
+    log = [Change(actor="doc1", seq=1, deps={}, start_op=1,
+                  ops=[Operation(action="makeList", obj=ROOT, opid=text, key="text")])]
+    ids = []
+    for r in refs:
+        ids.append((len(ids) + 2, "doc1"))
+        op = Operation(action="set", obj=text, opid=ids[-1],
+                       elem_id=HEAD if r < 0 else ids[r], insert=True,
+                       value=rng.choice("abcdefgh"))
+        log.append(Change(actor="doc1", seq=len(log) + 1,
+                          deps={"doc1": len(log)}, start_op=ids[-1][0], ops=[op]))
+    return to_program({"doc1": log})
+
+
+def _two_runs_at_one_ref(n):
+    """doc1 types "ab"; then doc1 and doc2 each type a run of ``n`` after
+    "a" concurrently, at equal counters, so doc2's ids are the greater."""
+    from benchmark.drivers._pool import to_program
+    from benchmark.reference.opids import HEAD, ROOT
+    from benchmark.reference.types import Change, Operation
+
+    text = (1, "doc1")
+    logs = {"doc1": [Change(actor="doc1", seq=1, deps={}, start_op=1, ops=[
+        Operation(action="makeList", obj=ROOT, opid=text, key="text")])],
+        "doc2": []}
+
+    def typed(actor, ctr, ref, deps):
+        log = logs[actor]
+        op = Operation(action="set", obj=text, opid=(ctr, actor), elem_id=ref,
+                       insert=True, value=actor[-1])
+        log.append(Change(actor=actor, seq=len(log) + 1, deps=deps,
+                          start_op=ctr, ops=[op]))
+        return op.opid
+
+    a = typed("doc1", 2, HEAD, {"doc1": 1})
+    typed("doc1", 3, a, {"doc1": 2})
+    for actor in ("doc1", "doc2"):
+        ref = a
+        for j in range(n):
+            deps = {actor: len(logs[actor])} if logs[actor] else {"doc1": 3}
+            ref = typed(actor, 4 + j, ref, deps)
+    return to_program(logs)
+
+
+def _b4(seed, ops):
+    from benchmark.drivers._pool import to_program
+    from benchmark.gen.editing_trace import Trace, history
+
+    cursor = {"typing_run": [1, 24], "backspace_run": [1, 8], "p_typing": 0.46,
+              "p_jump": 0.1, "alphabet": "abcdefghijklmnopqrstuvwxyz ,."}
+    ins = round(ops * 182315 / 259778)
+    return to_program(history(seed, Trace.of(dict(
+        cursor, inserts=ins, deletes=ops - ins))))
+
+
+#: name -> (workloads, slot capacity, page size, STREAM_CHUNK or None)
+PENDING_RUN_CASES = {
+    # a few hundred B4-shaped edits: runs, backspaces and jumps
+    "b4": lambda: ([_b4(5, 420), _b4(6, 300)], 512, 64, None),
+    # runs that straddle 16-entry stream chunks
+    "b4_chunked": lambda: ([_b4(7, 420), _b4(8, 150)], 512, 64, 16),
+    # one run of 300: the pending buffer fills at 128 and flushes
+    "long_run": lambda: ([_one_author([-1] + list(range(299)))], 512, 128, None),
+    # the same on a page wider than the buffer
+    "long_run_wide_page": lambda: (
+        [_one_author([-1] + list(range(299)))], 512, 256, None),
+    # the run cap falls to a narrow page's row: 32
+    "long_run_narrow_page": lambda: (
+        [_one_author([-1] + list(range(99)))], 512, 32, None),
+    # ten chars, then a run at the head: the slots run out 54 entries into
+    # a pending run
+    "overflow_mid_run": lambda: (
+        [_one_author([-1] + list(range(9)) + [-1] + list(range(10, 259)))],
+        192, 64, None),
+    # inserts whose reference is a pending entry other than the last
+    "ref_inside_run": lambda: (
+        [_one_author([-1, 0, 1, 2, 3, 1, 5, 6, 7, 2, 9, 10, 0, 12, 4, 14])],
+        512, 64, None),
+    # two actors' concurrent runs after one char: a greater id sits right
+    # of the lower-id run's reference
+    "two_actors": lambda: ([_two_runs_at_one_ref(40)], 512, 64, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PENDING_RUN_CASES))
+def test_ragged_kernel_pending_runs(case, monkeypatch):
+    """The Pallas kernel against the lax pool walk (pool planes, slot
+    counts and overflow flags bit for bit) and the padded apply (every
+    field), on histories whose typing runs the kernel splices once."""
+    import jax
+
+    from peritext_tpu.ops import ragged_pallas
+
+    workloads, slots, page, chunk = PENDING_RUN_CASES[case]()
+    if chunk is not None:
+        monkeypatch.setattr(ragged_pallas, "STREAM_CHUNK", chunk)
+    jax.clear_caches()  # the chunk is read when the kernel traces
+    try:
+        walk = _ragged_vs_padded(workloads, slots, 16, page, "lax")
+        kern = _ragged_vs_padded(workloads, slots, 16, page, "pallas_interpret")
+    finally:
+        jax.clear_caches()
+    assert np.array_equal(np.asarray(kern.pool_elem), np.asarray(walk.pool_elem))
+    assert np.array_equal(np.asarray(kern.pool_char), np.asarray(walk.pool_char))
+    for field in ("num_slots", "overflow"):
+        assert np.array_equal(np.asarray(kern.aux_field(field)),
+                              np.asarray(walk.aux_field(field)))
+    assert np.asarray(kern.aux_field("overflow")).any() == (case == "overflow_mid_run")
 
 
 @pytest.mark.parametrize("seed", range(4))

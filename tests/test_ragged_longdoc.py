@@ -76,6 +76,28 @@ def test_ragged_merge_equals_the_tree_reference(longdocs, counters):
     assert counters.get("merge.ragged_calls") == 2
 
 
+def _run_inserts(h):
+    """(inserts, inserts whose reference is the insert just before them)."""
+    ins = [op for log in h.values() for ch in log for op in ch.ops if op.insert]
+    return len(ins), sum(b.elem_id == a.opid for a, b in zip(ins, ins[1:]))
+
+
+def test_ragged_merge_counts_run_inserts(counters):
+    hs = [b4_history(seed, ops) for seed, ops in ((4, 700), (5, 400))]
+    docs = [to_program(h) for h in hs]
+    want = [sum(x) for x in zip(*map(_run_inserts, hs))]
+    assert 0 < want[1] < want[0]
+    DocBatch(layout="ragged", page_size=PAGE, slot_capacity=8 * PAGE,
+             mark_capacity=8, comment_capacity=8).merge(docs)
+    assert [counters.get("merge.ragged_inserts"),
+            counters.get("merge.ragged_run_inserts")] == want
+    # the padded apply has no insert kernel runs to count
+    DocBatch(slot_capacity=8 * PAGE, mark_capacity=8,
+             comment_capacity=8).merge(docs)
+    assert [counters.get("merge.ragged_inserts"),
+            counters.get("merge.ragged_run_inserts")] == want
+
+
 def test_padded_merge_emits_no_ragged_spans(longdocs):
     _, docs = longdocs
     tracer = Tracer()
